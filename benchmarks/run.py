@@ -16,8 +16,8 @@ recovery percentiles) and ``BENCH_obs.json`` (flight recorder off vs
 on on the overload trace: token identity, tracing overhead ratio, the
 predicted-vs-measured model-error rollup, a schema-validated trace
 excerpt) and ``BENCH_tp.json`` (the pinned workload replayed at every
-serving layout — 1x1 vs striped 1x2/2x2 meshes in forced-device
-subprocesses: token bit-identity across layouts, predicted
+serving layout — 1x1 vs striped 1x2/2x2 meshes on subsets of this
+process's devices: token bit-identity across layouts, predicted
 interconnect cost per window, and the measured remote page fraction
 against the (n-1)/n stripe model) into ``--json-dir``.  ``--only PATTERN`` filters sections by substring (an
 unknown pattern is an error listing the valid titles) — the CI
@@ -45,6 +45,13 @@ def main() -> None:
     ap.add_argument("--json-dir", default=".",
                     help="directory for the BENCH_*.json files")
     args = ap.parse_args()
+    # the layout comparison (BENCH_tp.json) meshes four devices in this
+    # one process; on a CPU host they are virtual (the flag touches only
+    # the CPU backend)
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import cost_sweep as cs
     from benchmarks import paper_tables as pt

@@ -982,78 +982,67 @@ def bench_spec_comparison(*, quick: bool = True, seed: int = 0,
     }
 
 
-# Pinned tensor-parallel workload: each layout replays EXACTLY this in a
-# fresh subprocess (the parent process pinned its device count at jax
-# import, so striped meshes need their own interpreter with
-# --xla_force_host_platform_device_count set first — the same technique
-# as tests/test_multidevice.py).
-_TP_CHILD = r'''
-import json, os, sys
-import numpy as np
-import jax
-sys.path.insert(0, sys.argv[1])
-data, model = int(sys.argv[2]), int(sys.argv[3])
-arch, seed = sys.argv[4], int(sys.argv[5])
-from repro.configs import get_tiny_config
-from repro.models import lm
-from repro.serving import PagedEngine
-from repro.launch.mesh import make_test_mesh
-
-cfg = get_tiny_config(arch)
-params = lm.init_params(jax.random.PRNGKey(0), cfg)
-mesh = make_test_mesh(data, model) if data * model > 1 else None
-n_nodes = max(model, 1)
-eng = PagedEngine(cfg, params, max_batch=3, page_size=4, n_pages=48,
-                  max_len=32, n_nodes=n_nodes, mesh=mesh,
-                  prefix_cache=True, trace=True)
-rng = np.random.default_rng(seed)
-shared = rng.integers(2, cfg.vocab_size, 6, dtype=np.int32)
-prompts = []
-for i in range(6):
-    tail = rng.integers(2, cfg.vocab_size, 6, dtype=np.int32)
-    head = shared if i >= 4 else rng.integers(2, cfg.vocab_size, 6,
-                                              dtype=np.int32)
-    prompts.append(np.concatenate([head, tail]))
-gens = [6, 9, 4, 7, 8, 5]
-owner_steps = np.zeros(n_nodes, np.int64)
-for i, (p, g) in enumerate(zip(prompts, gens)):
-    eng.submit(p, g, rid=f"r{i}")
-while eng.sched.waiting or eng.sched.running or eng.sched.prefilling:
-    eng.step()
-    for pages in eng.alloc.held.values():      # page-steps per owner node
-        for pg in pages:
-            owner_steps[pg % n_nodes] += 1
-eng.tracer.finalize(eng.sched.step_idx)
-report = eng.tracer.model_error_report()
-tot = int(owner_steps.sum())
-out = {
-    "predicted_s": sum(r["predicted_s"] for r in report.values()),
-    "measured_s": sum(r["measured_s"] for r in report.values()),
-    "predicted_comms_s": sum(r.get("predicted_comms_s", 0.0)
-                             for r in report.values()),
-    "comms_bytes": sum(r.get("comms_bytes", 0.0)
-                       for r in report.values()),
-    "measured_remote_frac": (1.0 - owner_steps[0] / tot) if tot else 0.0,
-    "steps": eng.steps_run,
-    "cow_copies": eng.cache.stats.cow_copies,
-    "preemptions": eng.metrics()["preemptions"],
-}
-tokens = {r.rid: [int(t) for t in r.tokens] for r in eng.sched.finished}
-out["tokens"] = tokens
-print("JSON:" + json.dumps(out))
-'''
-
 TP_LAYOUTS = ((1, 1), (1, 2), (2, 2))
+
+
+def _tp_replay(cfg, params, data: int, model: int, seed: int, devices):
+    """Replay the pinned prefix-sharing workload at one (data, model)
+    layout — a mesh over the first data*model of ``devices`` (1x1: no
+    mesh) — and return its traced payload plus per-request tokens."""
+    from repro.launch.mesh import make_test_mesh
+    from repro.serving import PagedEngine
+
+    mesh = make_test_mesh(data, model, devices=devices) \
+        if data * model > 1 else None
+    n_nodes = max(model, 1)
+    eng = PagedEngine(cfg, params, max_batch=3, page_size=4, n_pages=48,
+                      max_len=32, n_nodes=n_nodes, mesh=mesh,
+                      prefix_cache=True, trace=True)
+    rng = np.random.default_rng(seed)
+    shared = rng.integers(2, cfg.vocab_size, 6, dtype=np.int32)
+    prompts = []
+    for i in range(6):
+        tail = rng.integers(2, cfg.vocab_size, 6, dtype=np.int32)
+        head = shared if i >= 4 else rng.integers(2, cfg.vocab_size, 6,
+                                                  dtype=np.int32)
+        prompts.append(np.concatenate([head, tail]))
+    gens = [6, 9, 4, 7, 8, 5]
+    owner_steps = np.zeros(n_nodes, np.int64)
+    for i, (p, g) in enumerate(zip(prompts, gens)):
+        eng.submit(p, g, rid=f"r{i}")
+    while eng.sched.waiting or eng.sched.running or eng.sched.prefilling:
+        eng.step()
+        for pages in eng.alloc.held.values():   # page-steps per owner node
+            for pg in pages:
+                owner_steps[pg % n_nodes] += 1
+    eng.tracer.finalize(eng.sched.step_idx)
+    report = eng.tracer.model_error_report()
+    tot = int(owner_steps.sum())
+    out = {
+        "predicted_s": sum(r["predicted_s"] for r in report.values()),
+        "measured_s": sum(r["measured_s"] for r in report.values()),
+        "predicted_comms_s": sum(r.get("predicted_comms_s", 0.0)
+                                 for r in report.values()),
+        "comms_bytes": sum(r.get("comms_bytes", 0.0)
+                           for r in report.values()),
+        "measured_remote_frac": (1.0 - owner_steps[0] / tot) if tot else 0.0,
+        "steps": eng.steps_run,
+        "cow_copies": eng.cache.stats.cow_copies,
+        "preemptions": eng.metrics()["preemptions"],
+    }
+    tokens = {r.rid: [int(t) for t in r.tokens] for r in eng.sched.finished}
+    return out, tokens
 
 
 def bench_tp_comparison(*, quick: bool = True, seed: int = 0,
                         arch: str = "tiny-100m"):
     """Replay a pinned prefix-sharing workload through the paged engine
     at every serving layout — 1x1 single device, then 1x2 and 2x2
-    striped meshes — each in a fresh subprocess with the host device
-    count forced, asserting per-request greedy-token bit-identity
-    against the 1x1 baseline (the ISSUE's exactness gate: sharding is a
-    placement transform, never a sampler change).
+    striped meshes — all in this process, on subsets of one
+    ``jax.devices()`` list (four chips, or four virtual CPU devices
+    from ``--xla_force_host_platform_device_count=4``), asserting
+    per-request greedy-token bit-identity against the 1x1 baseline
+    (sharding is a placement transform, never a sampler change).
 
     Per layout the payload records the traced run's predicted vs
     measured seconds, the window-level predicted interconnect cost
@@ -1066,29 +1055,17 @@ def bench_tp_comparison(*, quick: bool = True, seed: int = 0,
 
     Returns the BENCH_tp.json payload.
     """
-    import json
-    import os
-    import subprocess
-    import sys
+    import jax
+    from repro.configs import get_tiny_config
+    from repro.models import lm
 
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(root, "src")
+    devices = jax.devices()
+    cfg = get_tiny_config(arch)
+    params = lm.init_params(jax.random.PRNGKey(0), cfg)
     layouts = []
     base_tokens = None
     for data, model in TP_LAYOUTS:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                            + " --xla_force_host_platform_device_count=4")
-        proc = subprocess.run(
-            [sys.executable, "-c", _TP_CHILD, src, str(data), str(model),
-             arch, str(seed)],
-            capture_output=True, text=True, env=env, cwd=root, timeout=900)
-        assert proc.returncode == 0, \
-            f"tp child {data}x{model} failed:\n{proc.stdout}\n{proc.stderr}"
-        payload = next(ln for ln in proc.stdout.splitlines()
-                       if ln.startswith("JSON:"))
-        child = json.loads(payload[len("JSON:"):])
-        tokens = child.pop("tokens")
+        child, tokens = _tp_replay(cfg, params, data, model, seed, devices)
         if base_tokens is None:
             base_tokens = tokens
         n = max(model, 1)
@@ -1294,6 +1271,8 @@ def main():
                     metavar="METRICS.json",
                     help="dump the unified metrics registry snapshot")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     spec_k = args.spec_k if args.spec_k == "auto" else int(args.spec_k)
     fault_plan = None
     if args.fault_plan == "chaos":

@@ -73,8 +73,8 @@ that ``benchmarks/run.py --json`` emits.
 
 * ``BENCH_tp.json`` (swallow.bench.tp/v1): the pinned prefix-sharing
   workload replayed at every serving layout — the 1x1 single-device
-  baseline plus striped (data, model) meshes, each in a forced-device
-  subprocess.  ``tokens_match`` must be true per layout and overall
+  baseline plus striped (data, model) meshes, all in one process on
+  subsets of its devices.  ``tokens_match`` must be true per layout and overall
   (striping the page pools is a placement transform — greedy tokens are
   bit-identical across meshes), every striped layout must price
   interconnect traffic (``predicted_comms_s`` / ``comms_bytes`` > 0 —

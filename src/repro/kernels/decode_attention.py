@@ -1,19 +1,22 @@
 """Pallas TPU decode attention (single query vs KV cache), GQA-aware.
 
-Grid (B, Kv, nT): for each kv head, its G query heads attend to the cache
-in (block_t, hd) tiles with an online softmax carried in VMEM scratch.
-The cache stays in its native (B, T, Kv, hd) layout — no H-expansion copy
-in HBM (decode is memory-bound; the cache read is the roofline term).
-Slots beyond ``pos`` are masked (ring/global semantics handled by the
-caller's mask offset).
+Grid (B, nT): each step DMAs one (block_t, Kv*hd) tile of the cache —
+every KV head of ``block_t`` slots — and each head's G query heads
+attend to their lane slice of it with an online softmax carried in VMEM
+scratch.  The cache is read in its flat (B, T, Kv*hd) serving layout, so
+a tile's last two dimensions are (block_t, Kv*hd): a multiple of the
+TPU's (8, 128) tiling whenever hd is a multiple of 128, with no
+H-expansion copy in HBM (decode is memory-bound; the cache read is the
+roofline term).  Slots beyond ``pos`` are masked.
 
 ``paged_decode_attention`` is the paged-KV variant behind the serving
 engine (Swallow §X-B: the KV cache as a striped distributed store): the
-cache lives in fixed-size pages (P, ps, Kv, hd) and each sequence names
+cache lives in fixed-size pages (P, ps, Kv*hd) and each sequence names
 its pages through a block-index table.  The table is a scalar-prefetch
 operand, so the BlockSpec index map DMAs exactly the pages the sequence
-owns — the kernel never assumes a contiguous cache, and per-sequence
-lengths replace the single shared ``pos``.
+owns — one whole page (all KV heads) per block — the kernel never
+assumes a contiguous cache, and per-sequence lengths replace the single
+shared ``pos``.
 """
 from __future__ import annotations
 
@@ -24,101 +27,119 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -2.0 ** 30
 
 
-def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                *, scale, softcap, block_t, nt):
-    ti = pl.program_id(2)
-
-    @pl.when(ti == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    pos = pos_ref[0]
-    t_start = ti * block_t
-
-    @pl.when(t_start <= pos)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)     # (bt, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+def _attend_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, t_start,
+                 pos, scale, softcap, n_kv, hd):
+    """Fold one (n, Kv*hd) cache tile into the online softmax of every
+    KV head: head h reads lanes [h*hd, (h+1)*hd) of the tile and updates
+    m/l (Kv, G, 1) and acc (Kv, G, hd) at its leading index."""
+    for h in range(n_kv):
+        lanes = pl.ds(h * hd, hd)
+        q = q_ref[0, h].astype(jnp.float32)                  # (G, hd)
+        k = k_ref[0, :, lanes].astype(jnp.float32)           # (n, hd)
+        v = v_ref[0, :, lanes].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
         slots = t_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(slots <= pos, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[h]                                     # (G, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[h] = l_ref[h] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        m_ref[h] = m_new
+
+
+def _init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _normalized(acc_ref, l_ref):
+    return acc_ref[...] / jnp.maximum(l_ref[...], 1e-37)
+
+
+def _scratch(n_kv, G, hd):
+    return [pltpu.VMEM((n_kv, G, 1), jnp.float32),
+            pltpu.VMEM((n_kv, G, 1), jnp.float32),
+            pltpu.VMEM((n_kv, G, hd), jnp.float32)]
+
+
+def _dec_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                *, scale, softcap, block_t, nt, n_kv, hd):
+    ti = pl.program_id(1)
+
+    @pl.when(ti == 0)
+    def _init():
+        _init_state(m_ref, l_ref, acc_ref)
+
+    pos = pos_ref[0]
+    t_start = ti * block_t
+
+    @pl.when(t_start <= pos)
+    def _compute():
+        _attend_tile(q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                     t_start=t_start, pos=pos, scale=scale, softcap=softcap,
+                     n_kv=n_kv, hd=hd)
 
     @pl.when(ti == nt - 1)
     def _finish():
-        o_ref[0, 0, ...] = (acc_ref[...]
-                            / jnp.maximum(l_ref[...], 1e-37)[:, None]
-                            ).astype(o_ref.dtype)
+        o_ref[0] = _normalized(acc_ref, l_ref).astype(o_ref.dtype)
+
+
+def _time_tile(T: int, block_t: int) -> int:
+    """Largest tile <= block_t that divides T and keeps the (8, 128)
+    tiling (a multiple of 8, or all of T)."""
+    return next((b for b in range(min(block_t, T), 0, -1)
+                 if T % b == 0 and b % 8 == 0), T)
 
 
 def decode_attention(q, k, v, pos, *, scale=None, softcap=None,
-                     block_t=512, interpret=True):
+                     block_t=512, interpret: bool):
     """q (B,H,hd); k,v (B,T,Kv,hd); pos () int32. Returns (B,H,hd)."""
     B, H, hd = q.shape
     T, Kv = k.shape[1], k.shape[2]
     G = H // Kv
     scale = hd ** -0.5 if scale is None else scale
-    bt = min(block_t, T)
-    while T % bt:
-        bt -= 1
+    bt = _time_tile(T, block_t)
     nt = T // bt
     qg = q.reshape(B, Kv, G, hd)
     pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
 
     kernel = functools.partial(_dec_kernel, scale=scale, softcap=softcap,
-                               block_t=bt, nt=nt)
+                               block_t=bt, nt=nt, n_kv=Kv, hd=hd)
+    tile = pl.BlockSpec((1, bt, Kv * hd), lambda b, t: (b, t, 0))
+    heads = pl.BlockSpec((1, Kv, G, hd), lambda b, t: (b, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(B, Kv, nt),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, G, hd), lambda b, kv, t: (b, kv, 0, 0)),
-            pl.BlockSpec((1, bt, 1, hd), lambda b, kv, t: (b, t, kv, 0)),
-            pl.BlockSpec((1, bt, 1, hd), lambda b, kv, t: (b, t, kv, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, kv, t: (b, kv, 0, 0)),
+        grid=(B, nt),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), heads, tile, tile],
+        out_specs=heads,
         out_shape=jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=_scratch(Kv, G, hd),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pos_arr, qg, k, v)
+    )(pos_arr, qg, k.reshape(B, T, Kv * hd), v.reshape(B, T, Kv * hd))
     return out.reshape(B, H, hd)
 
 
 def _paged_dec_kernel(*args, scale, softcap, page_size, pages_per_blk,
-                      n_blocks, masked, partials):
-    """Grid (B, Kv, n_blocks); each block sweeps ``pages_per_blk`` pages
+                      n_blocks, masked, partials, n_kv, hd):
+    """Grid (B, n_blocks); each block sweeps ``pages_per_blk`` pages
     (block_t = pages_per_blk * page_size cache slots) with one online
-    softmax carried in VMEM scratch.  Scalar-prefetch operands are the
-    block table, per-sequence pos, and (when ``masked``) a page
-    ownership mask; the remaining refs unpack as the q ref,
-    pages_per_blk k page refs, pages_per_blk v page refs, the
+    softmax per KV head carried in VMEM scratch.  Scalar-prefetch
+    operands are the block table, per-sequence pos, and (when
+    ``masked``) a page ownership mask; the remaining refs unpack as the
+    q ref, pages_per_blk k page refs, pages_per_blk v page refs, the
     output(s), then scratch.  ``partials`` emits the raw online-softmax
     state (acc, m, l) instead of the normalized output — the sharded
     caller merges per-stripe partials with psums."""
@@ -134,13 +155,11 @@ def _paged_dec_kernel(*args, scale, softcap, page_size, pages_per_blk,
     else:
         o_ref, m_ref, l_ref, acc_ref = refs[2 * m_:]
     b = pl.program_id(0)
-    blk = pl.program_id(2)
+    blk = pl.program_id(1)
 
     @pl.when(blk == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     pos = pos_ref[b]
     for i in range(m_):
@@ -153,50 +172,33 @@ def _paged_dec_kernel(*args, scale, softcap, page_size, pages_per_blk,
 
         @pl.when(live)
         def _compute(i=i, t_start=t_start):
-            q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-            k = k_refs[i][0, :, 0].astype(jnp.float32)     # (ps, hd)
-            v = v_refs[i][0, :, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-                * scale
-            if softcap is not None:
-                s = softcap * jnp.tanh(s / softcap)
-            slots = t_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(slots <= pos, s, NEG_INF)
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, s.max(axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-            acc_ref[...] = acc_ref[...] * corr[:, None] \
-                + jax.lax.dot_general(
-                    p, v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+            _attend_tile(q_ref, k_refs[i], v_refs[i], m_ref, l_ref, acc_ref,
+                         t_start=t_start, pos=pos, scale=scale,
+                         softcap=softcap, n_kv=n_kv, hd=hd)
 
     @pl.when(blk == n_blocks - 1)
     def _finish():
         if partials:
-            o_acc_ref[0, 0, ...] = acc_ref[...]
-            o_m_ref[0, 0, ...] = m_ref[...]
-            o_l_ref[0, 0, ...] = l_ref[...]
+            o_acc_ref[0] = acc_ref[...]
+            o_m_ref[0] = m_ref[...]
+            o_l_ref[0] = l_ref[...]
         else:
-            o_ref[0, 0, ...] = (acc_ref[...]
-                                / jnp.maximum(l_ref[...], 1e-37)[:, None]
-                                ).astype(o_ref.dtype)
+            o_ref[0] = _normalized(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
                            scale=None, softcap=None, block_t=None,
                            page_mask=None, partials=False,
-                           interpret=True):
+                           interpret: bool):
     """q (B,H,hd); k_pages/v_pages (P,ps,Kv,hd); block_tables (B,nmax)
     int32 physical page ids; pos (B,) int32 per-sequence last valid slot.
 
     Logical slot t of sequence b lives at page ``block_tables[b, t//ps]``,
     offset ``t % ps``.  Pages past ``pos[b]`` must still name a real page
     (the serving engine points them at the reserved null page 0); their
-    contribution is masked out exactly.
+    contribution is masked out exactly.  The pools are read as
+    (P, ps, Kv*hd) — the engine's own pool layout, so the reshape is free
+    there — and one grid step DMAs whole pages of every KV head.
 
     ``block_t`` is the time-tile sweep hook: a multiple of ``page_size``
     makes each grid step DMA ``block_t // page_size`` pages (each through
@@ -213,7 +215,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
     the normalized (B,H,hd) output, for cross-stripe psum merging.
     """
     B, H, hd = q.shape
-    ps, Kv = k_pages.shape[1], k_pages.shape[2]
+    P, ps, Kv = k_pages.shape[:3]
     nmax = block_tables.shape[1]
     G = H // Kv
     scale = hd ** -0.5 if scale is None else scale
@@ -236,54 +238,53 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, pos, *,
     kernel = functools.partial(_paged_dec_kernel, scale=scale,
                                softcap=softcap, page_size=ps,
                                pages_per_blk=m_, n_blocks=n_blocks,
-                               masked=masked, partials=partials)
+                               masked=masked, partials=partials,
+                               n_kv=Kv, hd=hd)
 
     def page_spec(i):
         # the block-index table drives the page DMA: page i of block p
         # of sequence b is physical page bt[b, p*m_+i] (pref[0] is the
         # table whatever the scalar-prefetch arity)
         return pl.BlockSpec(
-            (1, ps, 1, hd),
-            lambda b, kv, p, *pref, i=i: (pref[0][b, p * m_ + i], 0, kv, 0))
+            (1, ps, Kv * hd),
+            lambda b, p, *pref, i=i: (pref[0][b, p * m_ + i], 0, 0))
 
     def head_spec(shape):
-        return pl.BlockSpec(shape, lambda b, kv, p, *pref: (b, kv) +
-                            (0,) * (len(shape) - 2))
+        return pl.BlockSpec((1,) + shape, lambda b, p, *pref: (b,) +
+                            (0,) * len(shape))
 
     if partials:
-        out_specs = [head_spec((1, 1, G, hd)), head_spec((1, 1, G)),
-                     head_spec((1, 1, G))]
+        out_specs = [head_spec((Kv, G, hd)), head_spec((Kv, G, 1)),
+                     head_spec((Kv, G, 1))]
         out_shape = [jax.ShapeDtypeStruct((B, Kv, G, hd), jnp.float32),
-                     jax.ShapeDtypeStruct((B, Kv, G), jnp.float32),
-                     jax.ShapeDtypeStruct((B, Kv, G), jnp.float32)]
+                     jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32),
+                     jax.ShapeDtypeStruct((B, Kv, G, 1), jnp.float32)]
     else:
-        out_specs = head_spec((1, 1, G, hd))
+        out_specs = head_spec((Kv, G, hd))
         out_shape = jax.ShapeDtypeStruct((B, Kv, G, hd), q.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3 if masked else 2,
-        grid=(B, Kv, n_blocks),
+        grid=(B, n_blocks),
         in_specs=(
-            [head_spec((1, 1, G, hd))]
+            [head_spec((Kv, G, hd))]
             + [page_spec(i) for i in range(m_)]      # k pages
             + [page_spec(i) for i in range(m_)]),    # v pages
         out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
-        ],
+        scratch_shapes=_scratch(Kv, G, hd),
     )
     scalars = (bt, pos_arr, pm) if masked else (bt, pos_arr)
+    k_flat = k_pages.reshape(P, ps, Kv * hd)
+    v_flat = v_pages.reshape(P, ps, Kv * hd)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*scalars, qg, *([k_pages] * m_), *([v_pages] * m_))
+    )(*scalars, qg, *([k_flat] * m_), *([v_flat] * m_))
     if partials:
         acc, m, l = out
-        return acc, m, l
+        return acc, m[..., 0], l[..., 0]
     return out.reshape(B, H, hd)

@@ -22,10 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 NEG_INF = -2.0 ** 30
 
 
@@ -92,7 +88,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
                     softcap=None, block_q=512, block_kv=1024,
-                    interpret=True):
+                    interpret: bool):
     """q,k,v (B,S,H,hd), k/v pre-expanded to H heads. Returns (B,S,H,hd)."""
     B, S, H, hd = q.shape
     scale = hd ** -0.5 if scale is None else scale
@@ -128,7 +124,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -15,10 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nd):
     di = pl.program_id(3)
@@ -37,7 +33,7 @@ def _mm_kernel(x_ref, w_ref, o_ref, acc_ref, *, nd):
 
 
 def moe_gemm(x, w, *, block_c=128, block_f=512, block_d=512,
-             interpret=True):
+             interpret: bool):
     """x (E,C,D) @ w (E,D,F) -> (E,C,F) with fp32 accumulation."""
     E, C, D = x.shape
     F = w.shape[-1]
@@ -63,7 +59,7 @@ def moe_gemm(x, w, *, block_c=128, block_f=512, block_d=512,
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, c, f, d: (e, c, f)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,8 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the kernels lower natively (interpret=False); on the CPU container
-they run under interpret mode, which executes the kernel body with jnp
-semantics — bit-for-bit the same tiling logic, validated against ref.py.
+On TPU the kernels lower natively (interpret=False); on the CPU backend
+(the test suite) they run under interpret mode, which executes the kernel
+body with jnp semantics — the same tiling logic, validated against
+ref.py.  Any other backend is an error, not a silent interpreter run.
 """
 from __future__ import annotations
 
@@ -17,7 +18,12 @@ from repro.kernels import (decode_attention as _dec, flash_attention as _fa,
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"the Pallas kernels target the TPU (interpret "
+                           f"mode on the CPU); backend {backend!r} has "
+                           "neither")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "scale",

@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _rglru_kernel(a_ref, b_ref, o_ref, hT_ref, h_ref, *, block_s, ns):
     si = pl.program_id(2)
@@ -44,7 +40,8 @@ def _rglru_kernel(a_ref, b_ref, o_ref, hT_ref, h_ref, *, block_s, ns):
         hT_ref[0, ...] = h_ref[...]
 
 
-def rglru_scan(a, b, h0, *, block_s=256, block_w=256, interpret=True):
+def rglru_scan(a, b, h0, *, block_s=256, block_w=256,
+               interpret: bool):
     """a, b (B,S,W) fp32; h0 (B,W). Returns (h (B,S,W), hT (B,W)).
 
     h0 is folded into b[0] (b'_0 = a_0*h0 + b_0) so the kernel always
@@ -77,7 +74,7 @@ def rglru_scan(a, b, h0, *, block_s=256, block_w=256, interpret=True):
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

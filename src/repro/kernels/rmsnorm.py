@@ -12,10 +12,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _rms_kernel(x_ref, s_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
@@ -24,7 +20,7 @@ def _rms_kernel(x_ref, s_ref, o_ref, *, eps):
     o_ref[...] = (y * s_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def rmsnorm(x, scale, *, eps=1e-6, block_rows=256, interpret=True):
+def rmsnorm(x, scale, *, eps=1e-6, block_rows=256, interpret: bool):
     """x (..., D); scale (D,)."""
     orig_shape = x.shape
     D = x.shape[-1]
@@ -42,7 +38,7 @@ def rmsnorm(x, scale, *, eps=1e-6, block_rows=256, interpret=True):
         ],
         out_specs=pl.BlockSpec((bn, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xf, scale)

@@ -17,10 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax < 0.5 names it TPUCompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
                  o_ref, sT_ref, s_ref, *, L, nc):
@@ -70,7 +66,7 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref,
         sT_ref[0, 0] = s_ref[...]
 
 
-def rwkv6_scan(r, k, v, lw, u, S0, *, chunk=32, interpret=True):
+def rwkv6_scan(r, k, v, lw, u, S0, *, chunk=32, interpret: bool):
     """r,k,v,lw (B,S,H,K); u (H,K); S0 (B,H,K,V) fp32.
 
     Returns (o (B,S,H,V) fp32, S_T (B,H,K,V) fp32).
@@ -103,7 +99,7 @@ def rwkv6_scan(r, k, v, lw, u, S0, *, chunk=32, interpret=True):
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, lw, u, S0)

@@ -246,7 +246,9 @@ def report_fleet(args, cfg, eng, tokens_out: int):
         print(pod.attribution_table())
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
+    """The CLI's options and defaults (``chip_smoke.py`` parses its
+    phases through this too)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tiny-100m")
     ap.add_argument("--tiny", action="store_true")
@@ -329,7 +331,11 @@ def main():
     ap.add_argument("--trace-capacity", type=int, default=4096,
                     help="flight-recorder ring size (spans kept; "
                          "oldest evicted first)")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
     if args.prompt_len < 1:
         import sys
         print(f"error: --prompt-len must be >= 1 (got {args.prompt_len}): "
@@ -343,6 +349,8 @@ def main():
         os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
             f" --xla_force_host_platform_device_count={args.devices}"
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_config, get_tiny_config
     from repro.configs.base import ShapeConfig
     from repro.launch.mesh import make_test_mesh
@@ -372,12 +380,8 @@ def main():
               f"({best.tokens_per_s:.0f} tok/s)")
         mesh = make_layout_mesh(best.layout)
     elif args.data * args.model > 1:
-        import jax
-        if args.engine == "dense" \
-                or len(jax.devices()) >= args.data * args.model:
-            mesh = make_test_mesh(args.data, args.model)
-        # else: paged on a short host keeps host-side striping only
-        # (allocator accounting without device placement)
+        # raises when fewer devices exist than the mesh asks for
+        mesh = make_test_mesh(args.data, args.model)
 
     if args.engine == "paged":
         n_nodes = (predicted.layout.model if predicted is not None

@@ -41,6 +41,8 @@ def main():
         os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + \
             f" --xla_force_host_platform_device_count={args.devices}"
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs import get_config, get_tiny_config
     from repro.configs.base import ShapeConfig
     from repro.launch.mesh import make_test_mesh

@@ -255,23 +255,12 @@ def param_shardings(params, env: Optional[ShardingEnv] = None):
 
 
 # ---------------------------------------------------------------------------
-# shard_map version compat
+# shard_map
 # ---------------------------------------------------------------------------
 def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``shard_map`` across jax versions: newer releases take ``check_vma``,
-    older ones ``check_rep`` (same meaning for our purposes)."""
-    import inspect
-
-    try:
-        from jax import shard_map as sm  # jax >= 0.6
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as sm
-    kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if "check_vma" in inspect.signature(sm).parameters:
-        kw["check_vma"] = check_vma
-    else:
-        kw["check_rep"] = check_vma
-    return sm(f, **kw)
+    """``jax.shard_map`` with the repo's default of no varying-axis check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # ---------------------------------------------------------------------------

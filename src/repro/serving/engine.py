@@ -261,10 +261,13 @@ class PagedEngine:
         if self._stripe > 1:
             # place each pool leaf's page axis (third-from-last) over the
             # mesh: node d holds slab rows [d*P/M, (d+1)*P/M) — exactly
-            # the pages stripe_slab_index maps to it
+            # the pages stripe_slab_index maps to it.  Parameters are
+            # replicated on every device once here, not re-sent from the
+            # first device on every dispatch
             import jax
             from repro.parallel.sharding import SERVING_RULES, use_sharding
             with use_sharding(mesh, SERVING_RULES) as env:
+                self.params = jax.device_put(params, env.sharding())
                 self.pools = jax.device_put(
                     self.pools,
                     jax.tree.map(
@@ -508,6 +511,49 @@ class PagedEngine:
                       tenant=tenant, prompt=prompt, prompt_key=key, slo=slo)
         self.sched.submit(req)
         return req
+
+    def score(self, prompt, forced) -> np.ndarray:
+        """Teacher-forced logits of one sequence through the engine's own
+        paged steps — the logits-level oracle hook.  Row 0 is the
+        prefill's next-token logits; row i+1 the decode step fed
+        ``forced[i]``.  Returns float32 (1 + len(forced), V).  Borrows
+        slot 0 and fresh pages, so call it with no request in flight."""
+        if self.sched.waiting or self.sched.running or self.sched.prefilling:
+            raise RuntimeError("score needs an idle engine")
+        jnp = self._jnp
+        prompt = np.asarray(prompt, np.int32)
+        n = prompt.shape[0] + len(forced)
+        if n > self.max_len:
+            raise ValueError(f"score: {n} tokens exceed max_len "
+                             f"{self.max_len}")
+        rid = "__score__"
+        if self.alloc.alloc(rid, self.alloc.pages_for(n)) is None:
+            raise RuntimeError("score: no free pages for the sequence")
+        table = np.full((self.max_batch, self.nmax), NULL_PAGE, np.int32)
+        table[0] = self._block_row(rid)
+        out = []
+        try:
+            with self._use_env():
+                logits, self.pools = self._prefill(
+                    self.params, jnp.asarray(prompt[None]), self.pools,
+                    jnp.asarray(self._phys(table[0])))
+                out.append(logits[0, 0])
+                d_table = jnp.asarray(self._phys(table))
+                for i, t in enumerate(forced):
+                    # fresh host arrays each step: on the CPU backend
+                    # jnp.asarray may alias them while the step runs
+                    tokens = np.zeros((self.max_batch, 1), np.int32)
+                    pos = np.zeros((self.max_batch,), np.int32)
+                    tokens[0, 0] = t
+                    pos[0] = prompt.shape[0] + i
+                    _, logits, self.pools = self._serve(
+                        self.params, jnp.asarray(tokens), self.pools,
+                        d_table, jnp.asarray(pos))
+                    out.append(logits[0, 0])
+            return np.stack([np.asarray(o, np.float32) for o in out])
+        finally:
+            self.alloc.free(rid)
+            self._dirty = True        # slot state was borrowed
 
     # -- node failure / re-join (the fault plane's engine half) ------------
     def fail_node(self, node: int) -> set:

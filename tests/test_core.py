@@ -2,7 +2,7 @@
 property tests (topology routing, striping, scheduler)."""
 import pytest
 
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (energy, memory_server, network, nos, overlays,
                         ratio, topology)

@@ -123,3 +123,16 @@ def test_model_pallas_impl_matches_blocked():
         l_b, _ = lm.loss_fn(params, cfg.replace(impl="blocked"), batch)
         l_p, _ = lm.loss_fn(params, cfg.replace(impl="pallas"), batch)
         assert abs(float(l_b) - float(l_p)) < 5e-3, (arch, l_b, l_p)
+
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_interpret_only_on_cpu(monkeypatch, backend, want):
+    """Interpret mode is the CPU's alone; another backend is refused, not
+    silently interpreted."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is want

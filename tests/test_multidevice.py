@@ -22,6 +22,16 @@ def run_py(code: str, devices: int = 8, timeout: int = 900):
     return r.stdout
 
 
+def test_mesh_needs_enough_devices():
+    """A mesh larger than the visible devices is an error, never a
+    silently smaller placement."""
+    import jax
+    from repro.launch.mesh import make_test_mesh
+    n = len(jax.devices())
+    with pytest.raises(ValueError, match=f"needs {n + 1} devices"):
+        make_test_mesh(1, n + 1)
+
+
 def test_sharded_loss_matches_local():
     out = run_py("""
         import jax, jax.numpy as jnp

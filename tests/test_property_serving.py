@@ -24,14 +24,11 @@ chunked-vs-monolithic engine bit-identity property over drawn
 (chunk_tokens, prompt_len) pairs including non-page-aligned tails, and
 algebraic properties of the n-gram proposer/acceptance rule.
 
-Runs under the optional-hypothesis shim (tests/hypothesis_compat.py):
-with hypothesis absent (the base image) every ``@given`` test reports
-SKIPPED; the CI ``tests-hypothesis`` job installs hypothesis and runs
-them for real.  See docs/TESTING.md.
+See docs/TESTING.md.
 """
 import pytest
 
-from hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.serving import (ContinuousBatchScheduler, NULL_PAGE,
                            PageAllocator, Request, propose_ngram)
 from repro.serving.spec_decode import NGramSpec
@@ -402,8 +399,3 @@ def test_accept_rule_emits_exactly_the_greedy_tokens(draft, greedy):
     if a < len(draft):
         assert draft[a] != greedy[a]
 
-
-def test_hypothesis_shim_reports_presence():
-    """Documentation breadcrumb: tier-1 runs these as SKIPPED without
-    hypothesis; the tests-hypothesis CI job runs them for real."""
-    assert HAVE_HYPOTHESIS in (True, False)
