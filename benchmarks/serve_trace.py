@@ -519,10 +519,9 @@ def slo_stats(eng) -> dict:
         slo = get_slo(name)
         ttft = HistogramDigest.of(r.first_token_step - r.arrived_step
                                   for r in reqs)
-        wall = HistogramDigest.of((r.first_token_wall or 0.0)
-                                  - (r.arrived_wall or 0.0) for r in reqs)
-        tpot = HistogramDigest.of(((r.finished_wall or 0.0)
-                                   - (r.first_token_wall or 0.0))
+        wall = HistogramDigest.of(r.first_token_at - r.submitted_at
+                                  for r in reqs)
+        tpot = HistogramDigest.of((r.finished_at - r.first_token_at)
                                   / max(len(r.tokens) - 1, 1)
                                   for r in reqs)
         met = [r for r in reqs

@@ -70,7 +70,7 @@ class Job:
     pages_held = _gauge()              # KV pages currently allocated
     peak_pages = _gauge()
     tokens_out = _gauge()              # tokens emitted so far
-    queue_latency_s = _gauge()         # mean admission->first-token latency
+    queue_latency_s = _gauge()         # mean wait from submit to admission
     preemptions = _gauge()
     # -- prefix-sharing overlay (repro.serving.prefix_cache) ----------------
     shared_pages = _gauge()            # pages owned by the radix tree

@@ -208,6 +208,11 @@ def report_fleet(args, cfg, eng, tokens_out: int):
     fin = eng.sched.finished
     met_tokens = sum(len(r.tokens) for r in fin
                      if r.first_token_step <= r.deadline_step)
+    # measured on the requests' own lifecycle stamps (perf_counter)
+    from repro.serving.telemetry import HistogramDigest
+    queue_wait = HistogramDigest.of(r.admitted_at - r.submitted_at
+                                    for r in fin)
+    ttft = HistogramDigest.of(r.first_token_at - r.submitted_at for r in fin)
     # predicted-vs-measured attribution from the flight recorder (when
     # the run was traced): per-phase rollup feeds the fleet-level table
     model_error = None
@@ -221,7 +226,7 @@ def report_fleet(args, cfg, eng, tokens_out: int):
         "serve", pages_held=eng.alloc.pages_in_use,
         peak_pages=m["peak_pages"],
         tokens_out=tokens_out,
-        queue_latency_s=m["ttft_steps_mean"] * est.step_time_s,
+        queue_latency_s=queue_wait.mean,
         preemptions=m["preemptions"],
         energy_j=eng.steps_run * est.energy.total_j * est.layout.n_chips,
         shared_pages=m.get("shared_pages"),
@@ -230,7 +235,7 @@ def report_fleet(args, cfg, eng, tokens_out: int):
         accept_rate=m.get("accept_rate"),
         dispatches_per_token=m.get("dispatches_per_token"),
         spec_k=m.get("spec_k_mean"),
-        ttft_p99_s=m["ttft_steps_p99"] * est.step_time_s,
+        ttft_p99_s=ttft.percentile(99),
         ttft_target_s=slo.ttft_steps * est.step_time_s,
         goodput_frac=met_tokens / max(tokens_out, 1),
         pages_quarantined=m.get("pages_quarantined"),

@@ -432,32 +432,36 @@ def attend_decode_paged_sharded(q, pool: PagedAttnCache, block_tables,
         safe = jnp.where(mine, local, 0)
         if impl == "pallas":
             from repro.kernels import ops as kops
-            acc, m, l = kops.paged_decode_attention(
-                q_l.reshape(B, H, hd), k_l.reshape(L, ps, Kv, hd),
-                v_l.reshape(L, ps, Kv, hd), safe, pos_l,
-                scale=scale, softcap=softcap,
-                page_mask=mine.astype(jnp.int32), partials=True)
+            with jax.named_scope("attend"):
+                acc, m, l = kops.paged_decode_attention(
+                    q_l.reshape(B, H, hd), k_l.reshape(L, ps, Kv, hd),
+                    v_l.reshape(L, ps, Kv, hd), safe, pos_l,
+                    scale=scale, softcap=softcap,
+                    page_mask=mine.astype(jnp.int32), partials=True)
         else:
-            k = k_l[safe].reshape(B, T, Kv, hd)
-            v = v_l[safe].reshape(B, T, Kv, hd)
-            qg = q_l.reshape(B, Kv, H // Kv, hd)
-            s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
-                           preferred_element_type=jnp.float32) * scale
-            s = nn.softcap(s, softcap)
-            valid = (jnp.arange(T)[None, :] <= pos_l[:, None]) \
-                & jnp.repeat(mine, ps, axis=1)
-            s = jnp.where(valid[:, None, None, :], s, NEG_INF)
-            m = s.max(-1)                                   # (B,Kv,G)
-            p = jnp.exp(s - m[..., None])
-            l = p.sum(-1)
-            acc = jnp.einsum("bkgt,btkd->bkgd", p.astype(v.dtype), v,
-                             preferred_element_type=jnp.float32)
-        m_g = jax.lax.pmax(m, axes)
-        corr = jnp.exp(m - m_g)
-        l_g = jax.lax.psum(l * corr, axes)
-        acc_g = jax.lax.psum(acc * corr[..., None], axes)
-        o = acc_g / jnp.maximum(l_g[..., None], 1e-37)
-        return o.astype(q_l.dtype).reshape(B, 1, H * hd)
+            with jax.named_scope("kv_gather"):
+                k = k_l[safe].reshape(B, T, Kv, hd)
+                v = v_l[safe].reshape(B, T, Kv, hd)
+            with jax.named_scope("attend"):
+                qg = q_l.reshape(B, Kv, H // Kv, hd)
+                s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
+                               preferred_element_type=jnp.float32) * scale
+                s = nn.softcap(s, softcap)
+                valid = (jnp.arange(T)[None, :] <= pos_l[:, None]) \
+                    & jnp.repeat(mine, ps, axis=1)
+                s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+                m = s.max(-1)                               # (B,Kv,G)
+                p = jnp.exp(s - m[..., None])
+                l = p.sum(-1)
+                acc = jnp.einsum("bkgt,btkd->bkgd", p.astype(v.dtype), v,
+                                 preferred_element_type=jnp.float32)
+        with jax.named_scope("attend"):
+            m_g = jax.lax.pmax(m, axes)
+            corr = jnp.exp(m - m_g)
+            l_g = jax.lax.psum(l * corr, axes)
+            acc_g = jax.lax.psum(acc * corr[..., None], axes)
+            o = acc_g / jnp.maximum(l_g[..., None], 1e-37)
+            return o.astype(q_l.dtype).reshape(B, 1, H * hd)
 
     from jax.sharding import PartitionSpec
     pool_spec = env.spec("pages", None, None)
@@ -492,26 +496,29 @@ def attend_decode_paged(q, pool: PagedAttnCache, block_tables, pos, *,
     if impl == "pallas":
         from repro.kernels import ops as kops
         P_ = pool.k.shape[0]
-        o = kops.paged_decode_attention(
-            q.reshape(B, H, hd), pool.k.reshape(P_, ps, Kv, hd),
-            pool.v.reshape(P_, ps, Kv, hd), block_tables, pos,
-            scale=scale, softcap=softcap)
-        return o.reshape(B, 1, H * hd)
+        with jax.named_scope("attend"):
+            o = kops.paged_decode_attention(
+                q.reshape(B, H, hd), pool.k.reshape(P_, ps, Kv, hd),
+                pool.v.reshape(P_, ps, Kv, hd), block_tables, pos,
+                scale=scale, softcap=softcap)
+            return o.reshape(B, 1, H * hd)
     nmax = block_tables.shape[1]
     T = nmax * ps
     G = H // Kv
-    k = pool.k[block_tables].reshape(B, T, Kv, hd)
-    v = pool.v[block_tables].reshape(B, T, Kv, hd)
-    qg = q.reshape(B, Kv, G, hd)
-    s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    s = nn.softcap(s, softcap)
-    ok = jnp.arange(T)[None, :] <= pos[:, None]
-    s = jnp.where(ok[:, None, None, :], s, NEG_INF)
-    w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-    o = jnp.einsum("bkgt,btkd->bkgd", w.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32).astype(q.dtype)
-    return o.reshape(B, 1, H * hd)
+    with jax.named_scope("kv_gather"):
+        k = pool.k[block_tables].reshape(B, T, Kv, hd)
+        v = pool.v[block_tables].reshape(B, T, Kv, hd)
+    with jax.named_scope("attend"):
+        qg = q.reshape(B, Kv, G, hd)
+        s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = nn.softcap(s, softcap)
+        ok = jnp.arange(T)[None, :] <= pos[:, None]
+        s = jnp.where(ok[:, None, None, :], s, NEG_INF)
+        w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+        o = jnp.einsum("bkgt,btkd->bkgd", w.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32).astype(q.dtype)
+        return o.reshape(B, 1, H * hd)
 
 
 def paged_cache_scatter_suffix(pool: PagedAttnCache, k, v, block_row,
@@ -549,18 +556,20 @@ def attend_prefill_paged(q, pool: PagedAttnCache, block_row, start, *,
     T = nmax * ps
     Kv = n_kv
     G = H // Kv
-    k = pool.k[block_row].reshape(B, T, Kv, hd)
-    v = pool.v[block_row].reshape(B, T, Kv, hd)
-    qg = q.reshape(B, W, Kv, G, hd)
-    s = jnp.einsum("bwkgd,btkd->bkgwt", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    s = nn.softcap(s, softcap)
-    ok = jnp.arange(T)[None, :] <= (start + jnp.arange(W))[:, None]
-    s = jnp.where(ok[None, None, None], s, NEG_INF)
-    w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
-    o = jnp.einsum("bkgwt,btkd->bwkgd", w.astype(v.dtype), v,
-                   preferred_element_type=jnp.float32).astype(q.dtype)
-    return o.reshape(B, W, H * hd)
+    with jax.named_scope("kv_gather"):
+        k = pool.k[block_row].reshape(B, T, Kv, hd)
+        v = pool.v[block_row].reshape(B, T, Kv, hd)
+    with jax.named_scope("attend"):
+        qg = q.reshape(B, W, Kv, G, hd)
+        s = jnp.einsum("bwkgd,btkd->bkgwt", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        s = nn.softcap(s, softcap)
+        ok = jnp.arange(T)[None, :] <= (start + jnp.arange(W))[:, None]
+        s = jnp.where(ok[None, None, None], s, NEG_INF)
+        w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
+        o = jnp.einsum("bkgwt,btkd->bwkgd", w.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32).astype(q.dtype)
+        return o.reshape(B, W, H * hd)
 
 
 def apply_prefill_paged(p, cfg, x, pool: PagedAttnCache, block_row, start,
@@ -568,12 +577,15 @@ def apply_prefill_paged(p, cfg, x, pool: PagedAttnCache, block_row, start,
     """Paged suffix-prefill path: x (1,W,D) at positions start..;
     scatters the suffix kv then attends over the whole page run.
     Returns (out (1,W,D'), new pool)."""
-    q, k_new, v_new = _qkv(p, cfg, x, angles)
-    pool = paged_cache_scatter_suffix(pool, k_new, v_new, block_row,
-                                      start, n_valid)
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _qkv(p, cfg, x, angles)
+    with jax.named_scope("kv_append"):
+        pool = paged_cache_scatter_suffix(pool, k_new, v_new, block_row,
+                                          start, n_valid)
     o = attend_prefill_paged(q, pool, block_row, start, scale=_scale(cfg),
                              softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads)
-    return nn.matmul(o, p["wo"]), pool
+    with jax.named_scope("out_proj"):
+        return nn.matmul(o, p["wo"]), pool
 
 
 def paged_cache_from_prefill(pool: PagedAttnCache, k, v, block_row,
@@ -596,14 +608,18 @@ def paged_cache_from_prefill(pool: PagedAttnCache, k, v, block_row,
 
 def apply_decode_paged(p, cfg, x, pool: PagedAttnCache, block_tables,
                        pos, *, angles):
-    """Paged decode path: x (B,1,D), pos (B,). Returns (out, new pool)."""
-    q, k_new, v_new = _qkv(p, cfg, x, angles)
-    pool = paged_cache_update(pool, k_new, v_new, block_tables, pos)
+    """Paged decode path: x (B,1,D), pos (B,). Returns (out, new pool).
+    Its operations carry the scopes ``qkv``, ``kv_append``,
+    ``kv_gather``, ``attend`` and ``out_proj`` in the HLO metadata."""
+    with jax.named_scope("qkv"):
+        q, k_new, v_new = _qkv(p, cfg, x, angles)
+    with jax.named_scope("kv_append"):
+        pool = paged_cache_update(pool, k_new, v_new, block_tables, pos)
     o = attend_decode_paged(q, pool, block_tables, pos, scale=_scale(cfg),
                             softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads,
                             impl=cfg.impl)
-    out = nn.matmul(o, p["wo"])
-    return out, pool
+    with jax.named_scope("out_proj"):
+        return nn.matmul(o, p["wo"]), pool
 
 
 def cache_init(cfg, batch: int, max_len: int, window: Optional[int], dtype):

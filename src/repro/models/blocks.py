@@ -172,14 +172,17 @@ def apply_decode_paged(p, cfg, kind: str, x, pool, block_tables, pos, *,
     if kind != ATTN:
         raise NotImplementedError(
             f"paged decode supports global-attention layers only, got {kind!r}")
-    h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
     out, pool = attention.apply_decode_paged(p["attn"], cfg, h, pool,
                                              block_tables, pos,
                                              angles=angles)
-    x = x + _post(p, cfg, "ln1_post", out)
-    h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
-    out2, _ = _ffn_part(p, cfg, h2)
-    x = x + _post(p, cfg, "ln2_post", out2)
+    with jax.named_scope("out_proj"):
+        x = x + _post(p, cfg, "ln1_post", out)
+    with jax.named_scope("mlp"):
+        h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
+        out2, _ = _ffn_part(p, cfg, h2)
+        x = x + _post(p, cfg, "ln2_post", out2)
     return x, pool
 
 
@@ -192,14 +195,17 @@ def apply_prefill_paged(p, cfg, kind: str, x, pool, block_row, start,
         raise NotImplementedError(
             f"paged prefill supports global-attention layers only, "
             f"got {kind!r}")
-    h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
+    with jax.named_scope("qkv"):
+        h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
     out, pool = attention.apply_prefill_paged(p["attn"], cfg, h, pool,
                                               block_row, start, n_valid,
                                               angles=angles)
-    x = x + _post(p, cfg, "ln1_post", out)
-    h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
-    out2, _ = _ffn_part(p, cfg, h2)
-    x = x + _post(p, cfg, "ln2_post", out2)
+    with jax.named_scope("out_proj"):
+        x = x + _post(p, cfg, "ln1_post", out)
+    with jax.named_scope("mlp"):
+        h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
+        out2, _ = _ffn_part(p, cfg, h2)
+        x = x + _post(p, cfg, "ln2_post", out2)
     return x, pool
 
 

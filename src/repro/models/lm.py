@@ -475,9 +475,10 @@ def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
     pos (B,) int32 per-sequence positions (inactive slots: 0, with a
     null-page block row).  Returns (logits (B,1,V), new pools).
     """
-    x = embed_tokens(params, cfg, tokens)
-    positions = pos[:, None].astype(jnp.int32)
-    angles = _angles(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens)
+        positions = pos[:, None].astype(jnp.int32)
+        angles = _angles(cfg, positions)
 
     segs = make_segments(cfg)
     new_pools = []
@@ -501,8 +502,9 @@ def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
             x, new_seg = cycle_decode(seg_p, seg_pool, x)
         new_pools.append(new_seg)
 
-    h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    return head_logits(params, cfg, h), new_pools
+    with jax.named_scope("head"):
+        h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
+        return head_logits(params, cfg, h), new_pools
 
 
 def decode_window_paged(params, cfg, tokens, pools, block_tables, pos,
@@ -523,9 +525,10 @@ def decode_window_paged(params, cfg, tokens, pools, block_tables, pos,
     def body(carry, _):
         tok, p, pl = carry
         logits, pl = decode_step_paged(params, cfg, tok, pl, block_tables, p)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)      # (B,1)
-        nxt = jnp.where(active[:, None] > 0, nxt, tok)
-        p = p + active
+        with jax.named_scope("head"):
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B,1)
+            nxt = jnp.where(active[:, None] > 0, nxt, tok)
+            p = p + active
         return (nxt, p, pl), nxt[:, 0]
 
     (tok, pos, pools), toks = jax.lax.scan(body, (tokens, pos, pools),
@@ -540,11 +543,12 @@ def _suffix_forward_paged(params, cfg, tokens, pools, block_row, start,
     start..start+W-1) through every layer's ``apply_prefill_paged``,
     scattering its kv into the sequence's pages and attending causally
     over the whole page run.  Returns (hidden states (1,W,D), pools)."""
-    x = embed_tokens(params, cfg, tokens)
     B, W = tokens.shape
-    positions = (start + jnp.arange(W, dtype=jnp.int32))[None]
-    positions = jnp.broadcast_to(positions, (B, W))
-    angles = _angles(cfg, positions)
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens)
+        positions = (start + jnp.arange(W, dtype=jnp.int32))[None]
+        positions = jnp.broadcast_to(positions, (B, W))
+        angles = _angles(cfg, positions)
 
     segs = make_segments(cfg)
     new_pools = []
@@ -589,9 +593,11 @@ def prefill_suffix_paged(params, cfg, tokens, pools, block_row, start,
     """
     x, new_pools = _suffix_forward_paged(params, cfg, tokens, pools,
                                          block_row, start, n_valid)
-    h_last = jnp.take(x, n_valid - 1, axis=1)[:, None]     # (1,1,D)
-    h_last = nn.rmsnorm(h_last, params["final_norm"]["scale"], cfg.norm_eps)
-    return head_logits(params, cfg, h_last), new_pools
+    with jax.named_scope("head"):
+        h_last = jnp.take(x, n_valid - 1, axis=1)[:, None]  # (1,1,D)
+        h_last = nn.rmsnorm(h_last, params["final_norm"]["scale"],
+                            cfg.norm_eps)
+        return head_logits(params, cfg, h_last), new_pools
 
 
 def chunk_prefill_paged(params, cfg, tokens, pools, block_row, start,
@@ -638,8 +644,9 @@ def verify_window_paged(params, cfg, tokens, pools, block_row, start,
     """
     x, new_pools = _suffix_forward_paged(params, cfg, tokens, pools,
                                          block_row, start, n_valid)
-    h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-    return head_logits(params, cfg, h), new_pools
+    with jax.named_scope("head"):
+        h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
+        return head_logits(params, cfg, h), new_pools
 
 
 def decode_step(params, cfg, tokens, caches, pos, *, impl=None):

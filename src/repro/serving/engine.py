@@ -135,9 +135,12 @@ class PagedEngine:
     windows of up to ``max_window`` steps per dispatch; ``fused=False``
     is the per-step fallback with identical tokens.
 
-    ``trace=True`` arms the :class:`~repro.serving.telemetry.StepTracer`
-    flight recorder (request-lifecycle + dispatch spans, Chrome-trace
-    export); tracing never feeds back into scheduling, so tokens are
+    Every phase of :meth:`step` goes through :meth:`_span`, always: an
+    ``engine.<phase>`` annotation on the profiler's clock and the
+    registry counters ``engine.<phase>_s`` / ``_n``.  ``trace=True``
+    also arms the :class:`~repro.serving.telemetry.StepTracer` flight
+    recorder (request-lifecycle + dispatch spans, Chrome-trace export);
+    tracing never feeds back into scheduling, so tokens are
     bit-identical on or off.
     """
 
@@ -150,6 +153,8 @@ class PagedEngine:
     tokens_emitted = counter_attr()
     decode_time_s = counter_attr()
     spec_time_s = counter_attr()       # draft+verify subset of decode_time_s
+    step_calls = counter_attr()        # calls of step()
+    host_pass_s = counter_attr()       # step() time less its pulls
     h2d_syncs = counter_attr()
     d2h_syncs = counter_attr()
     block_row_writes = counter_attr()
@@ -179,6 +184,7 @@ class PagedEngine:
                  chunked_prefill: bool = False, chunk_tokens: int = 0,
                  fault_plan=None, trace: bool = False,
                  trace_capacity: int = 4096, mesh=None):
+        import jax
         import jax.numpy as jnp
         from repro.models import lm, modules as nn
 
@@ -231,6 +237,7 @@ class PagedEngine:
             else:
                 self.spec = NGramSpec(k=int(spec_k), max_n=spec_ngram)
         self._jnp = jnp
+        self._annotation = jax.profiler.TraceAnnotation
 
         self.alloc = PageAllocator(n_pages=n_pages, page_size=page_size,
                                    n_nodes=n_nodes, registry=self.registry)
@@ -264,7 +271,6 @@ class PagedEngine:
             # the pages stripe_slab_index maps to it.  Parameters are
             # replicated on every device once here, not re-sent from the
             # first device on every dispatch
-            import jax
             from repro.parallel.sharding import SERVING_RULES, use_sharding
             with use_sharding(mesh, SERVING_RULES) as env:
                 self.params = jax.device_put(params, env.sharding())
@@ -325,6 +331,8 @@ class PagedEngine:
         self.tokens_emitted = 0
         self.decode_time_s = 0.0
         self.spec_time_s = 0.0
+        self.step_calls = 0
+        self.host_pass_s = 0.0
         self.h2d_syncs = 0
         self.d2h_syncs = 0
         self.block_row_writes = 0
@@ -456,24 +464,46 @@ class PagedEngine:
                                  ici_bytes_per_chip=0.0,
                                  step_seconds=secs).total_j
 
-    _NULLCTX = contextlib.nullcontext()
+    @contextlib.contextmanager
+    def _span(self, phase: str, predfn=None, *,
+              program: Optional[str] = None, since: Optional[float] = None,
+              **extra):
+        """The engine's one span hook.  Always: a ``jax.profiler``
+        annotation ``engine.<phase>`` (recorded only while a profile is
+        taken, on the device trace's clock) and the registry counters
+        ``engine.<phase>_s`` (``perf_counter`` seconds) and
+        ``engine.<phase>_n``.  Phases never nest, so the ``_s`` counters
+        sum to at most the wall time of :meth:`step`.
 
-    def _span(self, phase: str, predfn=None, **extra):
-        """Dispatch-span context: a no-op when tracing is off (predfn is
-        never called — zero cost-model work), else a
-        :meth:`StepTracer.dispatch` span stamped with the current step
-        and the cost engine's (seconds, joules) prediction."""
-        if self.tracer is None:
-            return self._NULLCTX
-        vals = predfn() if predfn is not None else (0.0, 0.0)
-        ps, pj = vals[0], vals[1]
-        if len(vals) >= 4:
-            # striped engine: the predicted interconnect share rides the
-            # span so rollup_dispatch_events can attribute it per phase
-            extra = dict(extra, predicted_comms_s=vals[2],
-                         comms_bytes=vals[3])
-        return self.tracer.dispatch(phase, self.sched.step_idx,
-                                    predicted_s=ps, predicted_j=pj, **extra)
+        A phase is what the host does; the tracer's dispatch spans are
+        the step programs, each from its dispatch until its result is in
+        hand.  So with tracing on, a span given ``predfn`` also records
+        the :class:`StepTracer` dispatch span of ``program`` (default:
+        the phase, as for ``cow_copy``), measured from ``since``
+        (default: the span's start; a ``pull`` names the program whose
+        result it waits for and when that was dispatched) and stamped
+        with the current step and the cost engine's (seconds, joules)
+        prediction; ``predfn`` is never called otherwise."""
+        step = self.sched.step_idx
+        t0 = time.perf_counter()
+        try:
+            with self._annotation("engine." + phase):
+                yield
+        finally:
+            t1 = time.perf_counter()
+            self.registry.inc(f"engine.{phase}_s", t1 - t0)
+            self.registry.inc(f"engine.{phase}_n")
+            if self.tracer is not None and predfn is not None:
+                vals = predfn()
+                if len(vals) >= 4:
+                    # striped engine: the predicted interconnect share
+                    # rides the span so rollup_dispatch_events can
+                    # attribute it per phase
+                    extra = dict(extra, predicted_comms_s=vals[2],
+                                 comms_bytes=vals[3])
+                self.tracer.record_dispatch(
+                    program or phase, step, t0 if since is None else since,
+                    t1, predicted_s=vals[0], predicted_j=vals[1], **extra)
 
     def _flight_dump(self, reason: str) -> Optional[str]:
         """Invariant-violation post-mortem: dump the flight recorder's
@@ -835,105 +865,120 @@ class PagedEngine:
                 np.asarray(logits)
 
     # -- prefill (full, or cached-prefix COW + suffix) ---------------------
-    def _do_prefill(self, req: Request, row: np.ndarray, jnp) -> int:
-        """Write the request's prompt KV and return its first greedy
-        token.  On a prefix-cache hit, the cached prefix is skipped: the
-        block row already points at the shared pages, the divergence
-        page (if the match ends mid-page) is COW-copied on device, and
-        only the uncached suffix runs — through the teacher-forced
-        decode scan, no kernel change."""
-        L = req.cached_tokens
+    def _cow_copy(self, req: Request):
+        """On a prefix-cache hit that ends inside a page, copy that shared
+        page into the request's private page on device before any write
+        can touch it (both the monolithic and the chunked admission)."""
         match = req.prefix_match
-        if self.cache is None or L <= 0:
-            with self._span("prefill",
-                            lambda: self._predict_prefill(req.prompt_len),
-                            rid=req.rid, tokens=req.prompt_len):
-                logits, self.pools = self._prefill(
-                    self.params, jnp.asarray(req.prompt[None]), self.pools,
-                    jnp.asarray(self._phys(row)))
-                self.h2d_syncs += 1    # prompt + block row push
-                self.model_passes += 1
-                tok = int(jnp.argmax(logits, -1)[0, 0])
-                self.d2h_syncs += 1    # blocking first-token pull
-            self.prefill_tokens += req.prompt_len
-            return tok
-        if match is not None and match.cow_src is not None:
-            # diverging inside a shared page: copy it into the request's
-            # private page before any write can touch it
-            dst = self.alloc.held[req.rid][L // self.page_size]
-            with self._span("cow_copy", self._predict_cow, rid=req.rid):
-                self.pools = self._copy_page(
-                    self.pools, jnp.int32(self._phys(match.cow_src)),
-                    jnp.int32(self._phys(dst)))
+        if self.cache is None or match is None or match.cow_src is None:
+            return
+        jnp = self._jnp
+        dst = self.alloc.held[req.rid][req.cached_tokens // self.page_size]
+        with self._span("cow_copy", self._predict_cow, rid=req.rid):
+            self.pools = self._copy_page(
+                self.pools, jnp.int32(self._phys(match.cow_src)),
+                jnp.int32(self._phys(dst)))
             self.cache.stats.cow_copies += 1
             self.cache.release_cow(match)
+
+    def _prefill_admitted(self, req: Request) -> List[Request]:
+        """Monolithic admission: dispatch the prefill, pull the first
+        token and seat the request.  Returns ``[req]`` when it finished
+        at prefill (``gen == 1``)."""
+        cached = req.cached_tokens if self.cache is not None else 0
+        n = req.prompt_len - cached
+        width = self._pow2_ceil(n) if cached > 0 else n
+        t0 = time.perf_counter()
+        with self._span("prefill"):
+            row = self._block_row(req.rid)
+            logits = self._do_prefill(req, row)
+        tok = self._pull_first_token(
+            logits, "prefill", t0, lambda: self._predict_prefill(width),
+            rid=req.rid, tokens=n, cached=cached)
+        with self._span("complete"):
+            if self._seat(req, row, tok, self.sched.note_first_token):
+                return [req]               # gen == 1: finished at prefill
+        return []
+
+    def _do_prefill(self, req: Request, row: np.ndarray):
+        """Dispatch the admitted request's prefill and return its logits.
+        On a prefix-cache hit the cached prefix is skipped: the block row
+        already points at the shared pages (the divergence page was
+        COW-copied by :meth:`_cow_copy`) and only the uncached suffix
+        runs — through the teacher-forced decode scan, no kernel
+        change."""
+        jnp = self._jnp
+        L = req.cached_tokens
+        if self.cache is None or L <= 0:
+            logits, self.pools = self._prefill(
+                self.params, jnp.asarray(req.prompt[None]), self.pools,
+                jnp.asarray(self._phys(row)))
+            self.h2d_syncs += 1        # prompt + block row push
+            self.model_passes += 1
+            self.prefill_tokens += req.prompt_len
+            return logits
         suffix = np.asarray(req.prompt[L:], np.int32)
         slen = int(suffix.shape[0])
         k = self._pow2_ceil(slen)
         padded = np.zeros((1, k), np.int32)
         padded[0, :slen] = suffix
-        with self._span("prefill", lambda: self._predict_prefill(k),
-                        rid=req.rid, tokens=slen, cached=L):
-            logits, self.pools = self._suffix(
-                self.params, jnp.asarray(padded), self.pools,
-                jnp.asarray(self._phys(row)), jnp.int32(L),
-                jnp.int32(slen))
-            self.h2d_syncs += 1        # suffix + block row push
-            self.model_passes += 1
-            tok = int(jnp.argmax(logits, -1)[0, 0])
-            self.d2h_syncs += 1        # blocking first-token pull
+        logits, self.pools = self._suffix(
+            self.params, jnp.asarray(padded), self.pools,
+            jnp.asarray(self._phys(row)), jnp.int32(L), jnp.int32(slen))
+        self.h2d_syncs += 1            # suffix + block row push
+        self.model_passes += 1
         self.prefill_tokens += slen
+        return logits
+
+    def _pull_first_token(self, logits, program: str, since: float,
+                          predfn, **extra) -> int:
+        """The blocking pull of a prefill's first greedy token: a
+        ``pull`` phase like a decode window's, so ``engine.pull_s`` holds
+        every device wait; with tracing on it closes ``program``'s
+        dispatch span, measured from ``since``."""
+        with self._span("pull", predfn, program=program, since=since,
+                        **extra):
+            tok = int(self._jnp.argmax(logits, -1)[0, 0])
+        self.d2h_syncs += 1
         return tok
 
-    # -- chunked prefill (page-aligned slices between decode windows) ------
-    def _begin_chunked(self, req: Request):
-        """Chunked admission: no model pass yet — only the COW copy when
-        the prefix-cache match diverges mid-page, exactly as
-        :meth:`_do_prefill`'s hit branch would have done.  The block row
-        rides into each chunk dispatch directly (the device mirror never
-        sees a prefilling slot)."""
-        jnp = self._jnp
-        match = req.prefix_match
-        if self.cache is not None and match is not None \
-                and match.cow_src is not None:
-            dst = self.alloc.held[req.rid][req.cached_tokens
-                                           // self.page_size]
-            with self._span("cow_copy", self._predict_cow, rid=req.rid):
-                self.pools = self._copy_page(
-                    self.pools, jnp.int32(self._phys(match.cow_src)),
-                    jnp.int32(self._phys(dst)))
-            self.cache.stats.cow_copies += 1
-            self.cache.release_cow(match)
+    def _seat(self, req: Request, row: np.ndarray, tok: int,
+              note_first) -> bool:
+        """After a prefill's first token: graft the prompt's pages into
+        the prefix cache (they are immutable from this moment, decode
+        writes land past them, so later arrivals share instead of
+        re-prefilling), record the token through the scheduler's
+        ``note_first`` and seat the request.  Returns True when it
+        finished at prefill (``gen == 1``)."""
+        if self.cache is not None:
+            self.cache.insert(req.prompt_key, self.alloc.held[req.rid],
+                              req.prompt_len)
+        note_first(req, tok)
+        self.tokens_emitted += 1
+        if req.state == "running":
+            self._occupy_slot(req, row, tok)
+            return False
+        return True
 
-    def _do_chunk(self, req: Request, start: int, n: int) -> Optional[int]:
+    # -- chunked prefill (page-aligned slices between decode windows) ------
+    def _do_chunk(self, req: Request, row: np.ndarray, start: int, n: int):
         """Dispatch ONE page-aligned prefill chunk — positions ``start ..
         start+n-1`` — padded to a pow2 bucket (same rule as the suffix
         path, so a heavy-tailed length distribution compiles O(log)
-        kernels).  Returns the first greedy token when this was the final
-        chunk, else None (intermediate logits are never pulled: one d2h
-        per request, not per chunk)."""
+        kernels), and return its logits.  Only the final chunk's are
+        pulled (one d2h per request, not per chunk)."""
         jnp = self._jnp
-        row = self._block_row(req.rid)
         seg = np.asarray(req.prompt[start:start + n], np.int32)
-        w = self._pow2_ceil(n)
-        padded = np.zeros((1, w), np.int32)
+        padded = np.zeros((1, self._pow2_ceil(n)), np.int32)
         padded[0, :n] = seg
-        final = start + n == req.prompt_len
-        with self._span("chunk_prefill", lambda: self._predict_prefill(w),
-                        rid=req.rid, tokens=n, start=start):
-            logits, self.pools = self._chunk(
-                self.params, jnp.asarray(padded), self.pools,
-                jnp.asarray(self._phys(row)), jnp.int32(start),
-                jnp.int32(n))
-            self.h2d_syncs += 1        # chunk + block row push
-            self.model_passes += 1
-            self.chunk_dispatches += 1
-            tok = None
-            if final:
-                tok = int(jnp.argmax(logits, -1)[0, 0])
-                self.d2h_syncs += 1    # blocking first-token pull
+        logits, self.pools = self._chunk(
+            self.params, jnp.asarray(padded), self.pools,
+            jnp.asarray(self._phys(row)), jnp.int32(start), jnp.int32(n))
+        self.h2d_syncs += 1            # chunk + block row push
+        self.model_passes += 1
+        self.chunk_dispatches += 1
         self.prefill_tokens += n
-        return tok
+        return logits
 
     def _chunk_round(self, max_window: Optional[int]) -> List[Request]:
         """One chunk round: ask the scheduler for this window's budgeted
@@ -944,24 +989,29 @@ class PagedEngine:
             else max(1, min(self.max_window, max_window))
         if not self.fused:
             k_budget = 1
+        with self._span("plan"):
+            tasks = self.sched.plan_chunks(k_budget)
         finished: List[Request] = []
-        for req, start, n in self.sched.plan_chunks(k_budget):
-            tok = self._do_chunk(req, start, n)
-            if tok is None:
+        for req, start, n in tasks:
+            w = self._pow2_ceil(n)
+            predict = lambda: self._predict_prefill(w)   # noqa: E731
+            final = start + n == req.prompt_len
+            t0 = time.perf_counter()
+            # an intermediate chunk's span ends at its dispatch, the final
+            # chunk's at its first token
+            with self._span("chunk", None if final else predict,
+                            program="chunk_prefill", rid=req.rid, tokens=n,
+                            start=start):
+                row = self._block_row(req.rid)
+                logits = self._do_chunk(req, row, start, n)
+            if not final:
                 continue
-            row = self._block_row(req.rid)
-            if self.cache is not None:
-                # all prompt pages are immutable now — graft them, same
-                # as the monolithic path does right after prefill
-                self.cache.insert(req.prompt_key,
-                                  self.alloc.held[req.rid],
-                                  req.prompt_len)
-            self.sched.finish_prefill(req, tok)
-            self.tokens_emitted += 1
-            if req.state == "running":
-                self._occupy_slot(req, row, tok)
-            else:                      # gen == 1: finished at prefill
-                finished.append(req)
+            tok = self._pull_first_token(logits, "chunk_prefill", t0,
+                                         predict, rid=req.rid, tokens=n,
+                                         start=start)
+            with self._span("complete"):
+                if self._seat(req, row, tok, self.sched.finish_prefill):
+                    finished.append(req)
         return finished
 
     # -- one engine step (a window of >= 1 scheduler steps) ----------------
@@ -1057,38 +1107,36 @@ class PagedEngine:
         rule (:meth:`repro.serving.spec_decode.NGramSpec.accept`)."""
         jnp = self._jnp
         finished: List[Request] = []
-        cap = max(self.max_window, self.spec.k + 1)
-        if max_window is not None:
-            cap = max(1, min(cap, max_window))
-        k = self.sched.safe_horizon(cap)
-        self._refresh_slots()
-        active = dict(self.sched.running)
-        ks: Dict[int, int] = {}
-        host_drafts: Dict[int, List[int]] = {}
-        for slot, req in active.items():
-            K = self.spec.draft_k(req.tenant, k)
-            if K < 1:
-                continue
-            if self._spec_host:
-                d = self.spec.propose(req.prompt, req.tokens, K)
-                if not d:
-                    continue          # no match: ride the scan
-                host_drafts[slot] = d
-                ks[slot] = len(d)
-            else:
-                ks[slot] = K          # draft length discovered on device
-        kk_est = self._pow2_floor(min(k, self.max_window)) if self.fused \
-            else 1
-        ks = self._spec_gate(active, ks, kk_est)
-        host_drafts = {s: d for s, d in host_drafts.items() if s in ks}
-        scan_slots = [s for s in active if s not in ks]
-        t_dec = time.time()
-        advanced = 0          # scheduler-clock steps complete_step took
-        emitted_max = 0       # largest per-slot emission this window
-        tok_np = None
-        if scan_slots:
-            kk = kk_est
-            if ks:
+        with self._span("plan"):
+            cap = max(self.max_window, self.spec.k + 1)
+            if max_window is not None:
+                cap = max(1, min(cap, max_window))
+            k = self.sched.safe_horizon(cap)
+            active = dict(self.sched.running)
+            ks: Dict[int, int] = {}
+            host_drafts: Dict[int, List[int]] = {}
+            for slot, req in active.items():
+                K = self.spec.draft_k(req.tenant, k)
+                if K < 1:
+                    continue
+                if self._spec_host:
+                    d = self.spec.propose(req.prompt, req.tokens, K)
+                    if not d:
+                        continue      # no match: ride the scan
+                    host_drafts[slot] = d
+                    ks[slot] = len(d)
+                else:
+                    ks[slot] = K      # draft length discovered on device
+            kk_est = self._pow2_floor(min(k, self.max_window)) \
+                if self.fused else 1
+            ks = self._spec_gate(active, ks, kk_est)
+            host_drafts = {s: d for s, d in host_drafts.items() if s in ks}
+            scan_slots = [s for s in active if s not in ks]
+        kk = kk_est
+        with self._span("push"):
+            self._refresh_slots()
+            t_dec = time.perf_counter()
+            if scan_slots and ks:
                 # ONE sync event: canonical tokens/pos plus this window's
                 # masked rows/mask (speculating slots write the null
                 # page); the canonical d_block/d_active stay host-side —
@@ -1102,42 +1150,51 @@ class PagedEngine:
                 self.d_pos = jnp.asarray(self.pos)
                 d_bt, d_act = jnp.asarray(self._phys(bt)), jnp.asarray(act)
                 self.h2d_syncs += 1
-            else:
+            elif scan_slots:
                 self._push(force=not self.fused)
                 d_bt, d_act = self.d_block, self.d_active
-            with self._span("scan", lambda: self._predict_scan(kk),
-                            k=kk, slots=len(scan_slots)):
+            if ks and not self._spec_host:
+                # device chain inputs: history rows for any slot that fell
+                # behind (slot reuse / preemption / scan advance), plus
+                # page growth — in steady state only the latter moves.
+                # The scan neither reads them nor advances these slots
+                for slot in sorted(ks):
+                    self._sync_hist(slot, active[slot])
+                self._push_block()
+        advanced = 0          # scheduler-clock steps complete_step took
+        emitted_max = 0       # largest per-slot emission this window
+        tok_np = None
+        if scan_slots:
+            t_scan = time.perf_counter()
+            with self._span("dispatch"):
                 toks, d_tok, d_pos, self.pools = self._scan(
                     self.params, self.d_tokens, self.pools, d_bt,
                     self.d_pos, d_act, k=kk)
+            with self._span("pull", lambda: self._predict_scan(kk),
+                            program="scan", since=t_scan, k=kk,
+                            slots=len(scan_slots)):
                 tok_np = np.asarray(toks).reshape(self.max_batch, kk)
-            self.d2h_syncs += 1
-            self.decode_steps += kk
-            self.model_passes += kk
-            self.windows_run += 1
-            for j in range(kk):
-                emitted: Dict[int, int] = {s: int(tok_np[s, j])
-                                           for s in scan_slots}
-                self.decode_tokens += len(emitted)
-                self.tokens_emitted += len(emitted)
-                finished += self.sched.complete_step(emitted)
-            advanced = emitted_max = kk
-            if not ks:
-                # pure scan window: adopt the device carry, exactly like
-                # the plain fused path
-                self.d_tokens, self.d_pos = d_tok, d_pos
+            with self._span("complete"):
+                self.d2h_syncs += 1
+                self.decode_steps += kk
+                self.model_passes += kk
+                self.windows_run += 1
+                for j in range(kk):
+                    emitted: Dict[int, int] = {s: int(tok_np[s, j])
+                                               for s in scan_slots}
+                    self.decode_tokens += len(emitted)
+                    self.tokens_emitted += len(emitted)
+                    finished += self.sched.complete_step(emitted)
+                advanced = emitted_max = kk
+                if not ks:
+                    # pure scan window: adopt the device carry, exactly
+                    # like the plain fused path
+                    self.d_tokens, self.d_pos = d_tok, d_pos
         st = self.spec.stats
-        if ks and not self._spec_host:
-            # device chain inputs: history rows for any slot that fell
-            # behind (slot reuse / preemption / scan advance), plus page
-            # growth — in steady state only the latter moves
-            for slot in sorted(ks):
-                self._sync_hist(slot, active[slot])
-            self._push_block()
         for slot in sorted(ks):
             req = active[slot]
             K = ks[slot]
-            t_sp = time.time()
+            t_sp = time.perf_counter()
             if self._spec_host:
                 d = host_drafts[slot]
                 m = len(d)
@@ -1145,29 +1202,31 @@ class PagedEngine:
                 padded = np.zeros((1, W), np.int32)
                 padded[0, 0] = req.tokens[-1]
                 padded[0, 1:m + 1] = d
-                with self._span("draft_verify",
-                                lambda: self._predict_prefill(W),
-                                rid=req.rid, k=K, width=W):
+                with self._span("dispatch"):
                     logits, self.pools = self._verify(
                         self.params, jnp.asarray(padded), self.pools,
                         jnp.asarray(self._phys(self.block_tables[slot])),
                         jnp.int32(req.pos), jnp.int32(m + 1))
                     self.h2d_syncs += 1   # draft + block row push
+                with self._span("pull", lambda: self._predict_prefill(W),
+                                program="draft_verify", since=t_sp,
+                                rid=req.rid, k=K, width=W):
                     greedy = np.asarray(jnp.argmax(logits[0, :m + 1], -1),
                                         np.int32)
                     self.d2h_syncs += 1   # blocking verdict pull
                 out = self.spec.accept(d, greedy)   # updates stats
             else:
                 W = self._pow2_ceil(K + 1)
-                with self._span("draft_verify",
-                                lambda: self._predict_prefill(W),
-                                rid=req.rid, k=K, width=W):
+                with self._span("dispatch"):
                     (emit_d, n_emit_d, m_d, self.d_hist,
                      self.pools) = self._spec_step(
                         self.params, self.d_hist, self.pools, self.d_block,
                         jnp.int32(slot), jnp.int32(req.pos), jnp.int32(K),
                         W=W, max_n=self.spec.max_n,
                         min_n=self.spec.min_n)
+                with self._span("pull", lambda: self._predict_prefill(W),
+                                program="draft_verify", since=t_sp,
+                                rid=req.rid, k=K, width=W):
                     emit_np = np.asarray(emit_d)   # blocking verdict pull
                     n_emit, m = int(n_emit_d), int(m_d)
                     self.d2h_syncs += 1
@@ -1175,45 +1234,50 @@ class PagedEngine:
                 st.drafted += m
                 st.accepted += n_emit - 1
                 st.verifies += 1
-            self.spec_time_s += time.time() - t_sp
-            st.k_requested += K
-            self.spec.observe(req.tenant, m, len(out) - 1)
-            self.decode_steps += 1
-            self.model_passes += 1
-            self.windows_run += 1         # a verify IS a device dispatch
-            self.decode_tokens += len(out)
-            self.tokens_emitted += len(out)
-            finished += self.sched.complete_spec(req, out)
-            if req.state == "running" and len(out) <= m:
-                # rejected drafts: release their whole pages (the kept
-                # tail page's stale slots are masked by position and
-                # overwritten before the write position reaches them)
-                if self.alloc.truncate_to(req.rid, req.pos):
-                    st.rollbacks += 1
-                # pop-then-regrow can restore the same page COUNT with
-                # different physical pages — invisible to the (rid,
-                # preemptions, len) signature — so forget it: the next
-                # refresh must rewrite the device block row
-                self._slot_sig[req.slot] = None
-            if not self._spec_host:
-                # the fused step appended the emission on device, so the
-                # row now covers exactly pos+1 tokens again — the history
-                # holds only verified tokens, so rollback never touches it
-                self._hist_state[slot] = ((req.rid, req.preemptions),
-                                          req.pos + 1)
-            emitted_max = max(emitted_max, len(out))
-        if ks:
-            # the device carry is stale for speculating slots (and the
-            # scan saw masked rows): fold the mirror, re-push next window
-            for slot, req in self.sched.running.items():
-                self.tokens[slot, 0] = req.tokens[-1] if req.tokens else 0
-                self.pos[slot] = req.pos
-            self._dirty = True
-        elif tok_np is not None:
-            for slot, req in self.sched.running.items():
-                self.tokens[slot, 0] = int(tok_np[slot, advanced - 1])
-                self.pos[slot] = req.pos
-        self.decode_time_s += time.time() - t_dec
+            self.spec_time_s += time.perf_counter() - t_sp
+            with self._span("complete"):
+                st.k_requested += K
+                self.spec.observe(req.tenant, m, len(out) - 1)
+                self.decode_steps += 1
+                self.model_passes += 1
+                self.windows_run += 1     # a verify IS a device dispatch
+                self.decode_tokens += len(out)
+                self.tokens_emitted += len(out)
+                finished += self.sched.complete_spec(req, out)
+                if req.state == "running" and len(out) <= m:
+                    # rejected drafts: release their whole pages (the kept
+                    # tail page's stale slots are masked by position and
+                    # overwritten before the write position reaches them)
+                    if self.alloc.truncate_to(req.rid, req.pos):
+                        st.rollbacks += 1
+                    # pop-then-regrow can restore the same page COUNT with
+                    # different physical pages — invisible to the (rid,
+                    # preemptions, len) signature — so forget it: the
+                    # next refresh must rewrite the device block row
+                    self._slot_sig[req.slot] = None
+                if not self._spec_host:
+                    # the fused step appended the emission on device, so
+                    # the row now covers exactly pos+1 tokens again — the
+                    # history holds only verified tokens, so rollback
+                    # never touches it
+                    self._hist_state[slot] = ((req.rid, req.preemptions),
+                                              req.pos + 1)
+                emitted_max = max(emitted_max, len(out))
+        with self._span("complete"):
+            if ks:
+                # the device carry is stale for speculating slots (and the
+                # scan saw masked rows): fold the mirror, re-push next
+                # window
+                for slot, req in self.sched.running.items():
+                    self.tokens[slot, 0] = req.tokens[-1] if req.tokens \
+                        else 0
+                    self.pos[slot] = req.pos
+                self._dirty = True
+            elif tok_np is not None:
+                for slot, req in self.sched.running.items():
+                    self.tokens[slot, 0] = int(tok_np[slot, advanced - 1])
+                    self.pos[slot] = req.pos
+        self.decode_time_s += time.perf_counter() - t_dec
         # the window consumed max(scan depth, deepest verified emission)
         # scheduler-clock steps; complete_step already advanced `advanced`
         self.sched.step_idx += max(emitted_max - advanced, 0)
@@ -1224,81 +1288,35 @@ class PagedEngine:
         # prior (state is bounded by the tenant count — never forgotten)
         return finished
 
-    def step(self, max_window: Optional[int] = None) -> List[Request]:
-        """Plan, prefill admissions, decode one fused window (or one
-        step when ``fused=False``).  ``max_window`` additionally caps
-        this window (e.g. to the next trace arrival).  Returns requests
-        finished this window.
-
-        Under a striped mesh the whole step runs inside the sharding
-        env, so every dispatch resolves the ``pages`` axis and routes
-        paged attention through the shard_map owner-partial merge."""
-        with self._use_env():
-            return self._step_impl(max_window)
-
-    def _step_impl(self, max_window: Optional[int]) -> List[Request]:
-        jnp = self._jnp
-        if self.faults is not None:
-            # watchdog tick BEFORE planning: detections quarantine pages
-            # and reset victims, so this step's plan sees the degraded
-            # pool and never dispatches against a dead stripe
-            self.faults.on_step(self)
-        plan = self.sched.plan_step()
-        finished: List[Request] = []
-        for slot in range(self.max_batch):   # preempted/idle slots -> null
-            if slot not in self.sched.running \
-                    and self._slot_sig[slot] is not None:
-                self._clear_slot(slot)
-        for req in plan.admitted:
-            if self.sched.chunked:
-                self._begin_chunked(req)   # COW only; chunks do the rest
-                continue
-            row = self._block_row(req.rid)
-            tok = self._do_prefill(req, row, jnp)
-            if self.cache is not None:
-                # the prompt's full pages are immutable from this moment
-                # (decode writes land past them) — graft them so later
-                # arrivals share instead of re-prefilling
-                self.cache.insert(req.prompt_key,
-                                  self.alloc.held[req.rid],
-                                  req.prompt_len)
-            self.sched.note_first_token(req, tok)
-            self.tokens_emitted += 1
-            if req.state == "running":     # gen > 1: occupy the slot
-                self._occupy_slot(req, row, tok)
-            else:                          # gen == 1: finished at prefill
-                finished.append(req)
-        if self.sched.chunked and self.sched.prefilling:
-            # budgeted chunk round BEFORE the decode window: a prompt
-            # finishing its last chunk decodes in this very window, and
-            # decoding tenants see at most the budget's interference
-            finished += self._chunk_round(max_window)
-        if self.sched.running and self.spec is not None:
-            finished += self._spec_window(max_window)
-        elif self.sched.running:
+    def _decode_window(self, max_window: Optional[int]) -> List[Request]:
+        """One fused decode window (one step when ``fused=False``): push
+        the dirty slot state, dispatch, pull the window's tokens (its
+        one device wait) and record them."""
+        with self._span("plan"):
             k = self._pick_window(max_window) if self.fused else 1
+        with self._span("push"):
             self._refresh_slots()
             active = dict(self.sched.running)
-            t_dec = time.time()
-            with self._span("scan", lambda: self._predict_scan(k),
-                            k=k, slots=len(active)):
-                if self.fused:
-                    self._push()
-                    toks, self.d_tokens, self.d_pos, self.pools = \
-                        self._scan(self.params, self.d_tokens, self.pools,
-                                   self.d_block, self.d_pos, self.d_active,
-                                   k=k)
-                else:
-                    # legacy per-step path: push the whole bundle and pull
-                    # one token per scheduler step — O(1 syncs per token)
-                    self._push(force=True)
-                    toks, _, self.pools = self._serve(
-                        self.params, self.d_tokens, self.pools,
-                        self.d_block, self.d_pos)
-                tok_np = np.asarray(toks)  # blocks: decode-only timing
-            self.d2h_syncs += 1
-            self.decode_time_s += time.time() - t_dec
-            tok_np = tok_np.reshape(self.max_batch, k)
+            t_dec = time.perf_counter()
+            self._push(force=not self.fused)
+        with self._span("dispatch"):
+            if self.fused:
+                toks, self.d_tokens, self.d_pos, self.pools = self._scan(
+                    self.params, self.d_tokens, self.pools, self.d_block,
+                    self.d_pos, self.d_active, k=k)
+            else:
+                # legacy per-step path: push the whole bundle and pull one
+                # token per scheduler step — O(1 syncs per token)
+                toks, _, self.pools = self._serve(
+                    self.params, self.d_tokens, self.pools, self.d_block,
+                    self.d_pos)
+        with self._span("pull", lambda: self._predict_scan(k),
+                        program="scan", since=t_dec, k=k, slots=len(active)):
+            tok_np = np.asarray(toks).reshape(self.max_batch, k)
+        self.d2h_syncs += 1
+        self.decode_time_s += time.perf_counter() - t_dec
+        finished: List[Request] = []
+        with self._span("complete"):
             self.decode_steps += k
             self.model_passes += k
             self.windows_run += 1
@@ -1315,18 +1333,70 @@ class PagedEngine:
                 self.tokens[slot, 0] = int(tok_np[slot, k - 1])
                 self.pos[slot] = req.pos
             self.steps_run += k
-        else:
-            self.sched.step_idx += 1
-            self.steps_run += 1
-        for slot in range(self.max_batch):   # finished slots -> null
+        return finished
+
+    def step(self, max_window: Optional[int] = None) -> List[Request]:
+        """Plan, prefill admissions, decode one fused window (or one
+        step when ``fused=False``).  ``max_window`` additionally caps
+        this window (e.g. to the next trace arrival).  Returns requests
+        finished this window.  Counts the call (``step_calls``) and its
+        time less its ``pull`` phases, the waits for first tokens and
+        for the window's tokens (``host_pass_s``).
+
+        Under a striped mesh the whole step runs inside the sharding
+        env, so every dispatch resolves the ``pages`` axis and routes
+        paged attention through the shard_map owner-partial merge."""
+        t0 = time.perf_counter()
+        pulled = self.registry.counter("engine.pull_s")
+        with self._use_env():
+            finished = self._step_impl(max_window)
+        self.step_calls += 1
+        self.host_pass_s += time.perf_counter() - t0 \
+            - (self.registry.counter("engine.pull_s") - pulled)
+        return finished
+
+    def _clear_idle_slots(self):
+        """Null the mirror rows of slots no running request holds
+        (preempted, finished or never used since their last clear)."""
+        for slot in range(self.max_batch):
             if slot not in self.sched.running \
                     and self._slot_sig[slot] is not None:
                 self._clear_slot(slot)
-        self.peak_pages = max(self.peak_pages, self.alloc.pages_in_use)
-        if self.tracer is not None:
-            # per-node occupancy counter track (Perfetto stacked counters)
-            self.tracer.counter_sample(self.sched.step_idx,
-                                       self.alloc.occupancy_by_node())
+
+    def _step_impl(self, max_window: Optional[int]) -> List[Request]:
+        with self._span("plan"):
+            if self.faults is not None:
+                # watchdog tick BEFORE planning: detections quarantine
+                # pages and reset victims, so this step's plan sees the
+                # degraded pool and never dispatches against a dead stripe
+                self.faults.on_step(self)
+            plan = self.sched.plan_step()
+            self._clear_idle_slots()
+        finished: List[Request] = []
+        for req in plan.admitted:
+            self._cow_copy(req)
+            if not self.sched.chunked:     # chunked: the chunks prefill
+                finished += self._prefill_admitted(req)
+        if self.sched.chunked and self.sched.prefilling:
+            # budgeted chunk round BEFORE the decode window: a prompt
+            # finishing its last chunk decodes in this very window, and
+            # decoding tenants see at most the budget's interference
+            finished += self._chunk_round(max_window)
+        if self.sched.running and self.spec is not None:
+            finished += self._spec_window(max_window)
+        elif self.sched.running:
+            finished += self._decode_window(max_window)
+        else:
+            self.sched.step_idx += 1
+            self.steps_run += 1
+        with self._span("complete"):
+            self._clear_idle_slots()
+            self.peak_pages = max(self.peak_pages, self.alloc.pages_in_use)
+            if self.tracer is not None:
+                # per-node occupancy counter track (Perfetto stacked
+                # counters)
+                self.tracer.counter_sample(self.sched.step_idx,
+                                           self.alloc.occupancy_by_node())
         return finished
 
     def run(self, max_steps: int = 100_000) -> List[Request]:

@@ -106,10 +106,15 @@ class Request:
                                            # when the first token re-lands
     transient_rejections: int = 0    # dispatch faults absorbed by backoff
     backoff_until: int = 0           # not admissible before this step
-    # wall stamps (telemetry only — scheduling never reads the wall clock)
-    arrived_wall: float = 0.0
-    first_token_wall: float = 0.0
-    finished_wall: float = 0.0
+    # lifecycle stamps on time.perf_counter(), the clock of the profiler
+    # and of a load generator (telemetry only — scheduling never reads
+    # them): submitted; admitted by the admission that led to the first
+    # token; first token, the first time (a recompute after preemption
+    # does not move it); finished
+    submitted_at: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
+    finished_at: Optional[float] = None
     # -- prefix-cache state (set at admission, consumed by the engine) -----
     cached_tokens: int = 0           # prompt tokens served from shared pages
     prefix_match: Optional[object] = None   # prefix_cache.PrefixMatch
@@ -187,9 +192,20 @@ class ContinuousBatchScheduler:
         self.recovery_steps: List[int] = []   # fault-reset -> first-token
 
     def _trace(self, req: Request, state: str) -> None:
-        """Emit one lifecycle transition to the flight recorder (no-op
-        without a tracer; never read back — tracing cannot perturb
-        scheduling)."""
+        """Stamp one lifecycle transition on the request (always) and
+        emit it to the flight recorder (when one is armed).  Neither is
+        ever read back — tracing cannot perturb scheduling."""
+        now = time.perf_counter()
+        if state == "queued":
+            req.submitted_at = now
+        elif req.first_token_at is None and state in ("prefilling",
+                                                      "running"):
+            if state == "prefilling":
+                req.admitted_at = now
+            else:
+                req.first_token_at = now
+        elif state == "finished":
+            req.finished_at = now
         if self.tracer is not None:
             self.tracer.request_event(req.rid, state, self.step_idx,
                                       tenant=req.tenant)
@@ -205,7 +221,6 @@ class ContinuousBatchScheduler:
         req.seq = self._next_seq
         self._next_seq += 1
         req.deadline_step = get_slo(req.slo).deadline(req.arrived_step)
-        req.arrived_wall = time.time()
         self.waiting.append(req)
         self._trace(req, "queued")
         self._sort_waiting()
@@ -648,15 +663,15 @@ class ContinuousBatchScheduler:
     def note_first_token(self, req: Request, token: int):
         if self.cache is not None and req.prefix_match is not None:
             # prefill is done.  In engine flows this release is a no-op —
-            # _do_prefill drops the COW-source reference right after its
-            # device copy — but the scheduler is also driven engine-less
-            # (host-only tests, cost studies), and there this is the ONLY
-            # balance point for acquire()'s temporary COW reference.
+            # the engine's _cow_copy drops the COW-source reference right
+            # after its device copy — but the scheduler is also driven
+            # engine-less (host-only tests, cost studies), and there this
+            # is the ONLY balance point for acquire()'s temporary COW
+            # reference.
             self.cache.release_cow(req.prefix_match)
             req.prefix_match = None
         req.tokens.append(token)
         req.first_token_step = self.step_idx
-        req.first_token_wall = time.time()
         self._trace(req, "running")
         if req.recovered_step is not None:
             # recovery latency: fault reset -> the recompute's first token.
@@ -717,7 +732,6 @@ class ContinuousBatchScheduler:
             self.running.pop(req.slot, None)
         req.state, req.slot = "finished", None
         req.finished_step = self.step_idx
-        req.finished_wall = time.time()
         self.finished.append(req)
         self._trace(req, "finished")
         return True

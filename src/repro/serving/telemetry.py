@@ -50,7 +50,6 @@ import math
 import os
 import time
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
@@ -388,22 +387,18 @@ class StepTracer:
         self.recorded += 1
 
     # -- dispatch spans ----------------------------------------------------
-    @contextmanager
-    def dispatch(self, phase: str, step: int, *, predicted_s: float = 0.0,
-                 predicted_j: float = 0.0, **extra):
-        """Wrap one device dispatch; measured wall time is the context
-        body's duration, recorded next to the cost engine's prediction."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter()
-            args = {"predicted_s": float(predicted_s),
-                    "predicted_j": float(predicted_j),
-                    "measured_s": t1 - t0}
-            args.update(extra)
-            self._record(Span(phase, "dispatch", "dispatch", phase,
-                              step, step, t0, t1, args))
+    def record_dispatch(self, phase: str, step: int, t0: float, t1: float,
+                        *, predicted_s: float = 0.0,
+                        predicted_j: float = 0.0, **extra) -> None:
+        """Record one device dispatch, from ``t0`` to ``t1`` on
+        ``perf_counter``, next to the cost engine's prediction (the
+        engine's span hook measures it and hands it here)."""
+        args = {"predicted_s": float(predicted_s),
+                "predicted_j": float(predicted_j),
+                "measured_s": t1 - t0}
+        args.update(extra)
+        self._record(Span(phase, "dispatch", "dispatch", phase,
+                          step, step, t0, t1, args))
 
     # -- request lifecycle spans ------------------------------------------
     def request_event(self, rid: str, state: str, step: int, *,

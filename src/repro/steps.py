@@ -83,6 +83,18 @@ def constrain_paged_pools(pools):
     return jax.tree.map(pin, pools)
 
 
+def _scoped(fn):
+    """Run a step function under a ``jax.named_scope`` of its own name,
+    so every operation of the program carries it in the HLO metadata
+    the profiler reports (metadata only: the compiled code is the
+    same)."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(fn.__name__):
+            return fn(*args, **kwargs)
+    return scoped
+
+
 def make_paged_prefill_step(cfg: ModelConfig, impl: Optional[str] = None):
     """Prefill ONE sequence straight into the paged KV pools.
 
@@ -90,14 +102,17 @@ def make_paged_prefill_step(cfg: ModelConfig, impl: Optional[str] = None):
     (next-token logits (1,1,V), updated pools).  Jit with the pools
     donated — the scatter is in-place on device.
     """
+    @_scoped
     def prefill_paged(params, tokens, pools, block_row):
         h, raw, _ = lm.forward(params, cfg, tokens, mode="prefill",
                                impl=impl)
-        pools = lm.paged_from_prefill(cfg, pools, raw, block_row)
-        h_last = nn.rmsnorm(h[:, -1:], params["final_norm"]["scale"],
-                            cfg.norm_eps)
-        return lm.head_logits(params, cfg, h_last), \
-            constrain_paged_pools(pools)
+        with jax.named_scope("kv_append"):
+            pools = lm.paged_from_prefill(cfg, pools, raw, block_row)
+        with jax.named_scope("head"):
+            h_last = nn.rmsnorm(h[:, -1:], params["final_norm"]["scale"],
+                                cfg.norm_eps)
+            logits = lm.head_logits(params, cfg, h_last)
+        return logits, constrain_paged_pools(pools)
     return prefill_paged
 
 
@@ -107,10 +122,12 @@ def make_paged_serve_step(cfg: ModelConfig):
     (params, tokens (B,1), pools, block_tables (B,nmax), pos (B,)) ->
     (next tokens (B,1), logits, updated pools).
     """
+    @_scoped
     def serve_paged(params, tokens, pools, block_tables, pos):
         logits, pools = lm.decode_step_paged(params, cfg, tokens, pools,
                                              block_tables, pos)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, logits, constrain_paged_pools(pools)
     return serve_paged
 
@@ -125,6 +142,7 @@ def make_paged_suffix_prefill(cfg: ModelConfig):
     pools donated; the padded width W is the only retrace axis (the
     engine buckets it to powers of two).
     """
+    @_scoped
     def suffix_prefill(params, tokens, pools, block_row, start, n_valid):
         logits, pools = lm.prefill_suffix_paged(params, cfg, tokens, pools,
                                                 block_row, start, n_valid)
@@ -149,6 +167,7 @@ def make_chunk_prefill(cfg: ModelConfig):
     prompt-length distribution compiles O(log max_chunk) kernels instead
     of one per length.
     """
+    @_scoped
     def chunk_prefill(params, tokens, pools, block_row, start, n_valid):
         logits, pools = lm.chunk_prefill_paged(params, cfg, tokens, pools,
                                                block_row, start, n_valid)
@@ -168,6 +187,7 @@ def make_verify_window(cfg: ModelConfig):
     donated; the padded width W is the only retrace axis (the engine
     buckets it to powers of two).
     """
+    @_scoped
     def verify_window(params, tokens, pools, block_row, start, n_valid):
         logits, pools = lm.verify_window_paged(params, cfg, tokens, pools,
                                                block_row, start, n_valid)
@@ -207,6 +227,7 @@ def make_spec_draft_verify(cfg: ModelConfig):
     """
     from repro.serving.spec_decode import device_propose
 
+    @_scoped
     def draft_verify(params, history, pools, block_tables, slot, start, k,
                      *, W: int, max_n: int, min_n: int):
         H = history.shape[-1]
@@ -253,6 +274,7 @@ def make_page_copy():
     diverging request overwrites before its position ever reaches them.
     Jit with the pools donated; src/dst are traced scalars (one compile).
     """
+    @_scoped
     def copy_page(pools, src, dst):
         pools = jax.tree.map(
             lambda a: a.at[..., dst, :, :].set(a[..., src, :, :]), pools)
@@ -269,6 +291,7 @@ def make_paged_serve_scan(cfg: ModelConfig):
     ``static_argnames=("k",)`` and the pools donated; one dispatch and
     one host sync then cover K decode steps instead of one.
     """
+    @_scoped
     def serve_scan(params, tokens, pools, block_tables, pos, active, *,
                    k: int):
         emitted, last, pos, pools = lm.decode_window_paged(

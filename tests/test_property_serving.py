@@ -291,10 +291,11 @@ def test_chunked_scheduler_random_traces_conserve_and_progress(
        st.integers(2, 5))
 def test_chunked_engine_bit_identical_to_monolithic(chunk_tokens, plens,
                                                     gen):
-    """Chunked prefill is bit-identical to the monolithic engine for ANY
-    drawn (chunk_tokens, prompt_len) pair — page-aligned or not, final
-    chunks partial or not.  Few examples (the engine compiles per pow2
-    prefill bucket), but each drives the full dispatch path."""
+    """Chunked prefill is bit-identical to the monolithic engine, and to
+    itself with tracing on, for ANY drawn (chunk_tokens, prompt_len)
+    pair — page-aligned or not, final chunks partial or not.  Few
+    examples (the engine compiles per pow2 prefill bucket), but each
+    drives the full dispatch path."""
     import sys
     sys.path.insert(0, "tests")
     import numpy as np
@@ -305,11 +306,11 @@ def test_chunked_engine_bit_identical_to_monolithic(chunk_tokens, plens,
     prompts = [seeded_prompts(cfg, 1, plen, seed=60 + i)[0]
                for i, plen in enumerate(plens)]
 
-    def run(chunked):
+    def run(chunked, trace=False):
         eng = make_engine(cfg, params, max_batch=2, page_size=4,
                           n_pages=48, max_len=max_len, fused=True,
                           max_window=4, chunked_prefill=chunked,
-                          chunk_tokens=chunk_tokens)
+                          chunk_tokens=chunk_tokens, trace=trace)
         for i, (p, g) in enumerate(zip(prompts, [gen] * len(prompts))):
             eng.submit(np.asarray(p), g, rid=f"r{i}", slo="interactive")
         toks = {r.rid: list(r.tokens) for r in eng.run()}
@@ -317,7 +318,10 @@ def test_chunked_engine_bit_identical_to_monolithic(chunk_tokens, plens,
         assert eng.alloc.check_conservation()
         return toks
 
-    assert run(True) == run(False), (chunk_tokens, plens, gen)
+    chunked = run(True)
+    assert chunked == run(False), (chunk_tokens, plens, gen)
+    # the flight recorder observes, never steers
+    assert chunked == run(True, trace=True), (chunk_tokens, plens, gen)
 
 
 @settings(max_examples=60, deadline=None)

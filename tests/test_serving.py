@@ -577,9 +577,10 @@ def test_chunked_requests_carry_wall_and_deadline_stamps():
     assert req.deadline_step == req.arrived_step \
         + get_slo("interactive").ttft_steps
     eng.run()
-    assert req.arrived_wall is not None
-    assert req.first_token_wall >= req.arrived_wall
-    assert req.finished_wall >= req.first_token_wall
+    assert req.submitted_at is not None
+    assert req.admitted_at >= req.submitted_at
+    assert req.first_token_at >= req.admitted_at
+    assert req.finished_at >= req.first_token_at
 
 
 def test_get_slo_rejects_unknown_class():

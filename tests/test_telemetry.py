@@ -9,6 +9,7 @@ docs/FAULT_TOLERANCE.md).
 import glob
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -124,8 +125,7 @@ def test_counter_attr_descriptor_reads_and_writes_registry():
 def test_tracer_ring_evicts_oldest_and_counts_drops():
     tr = StepTracer(capacity=8)
     for i in range(20):
-        with tr.dispatch("scan", i):
-            pass
+        tr.record_dispatch("scan", i, float(i), i + 0.5)
     assert tr.recorded == 20 and tr.dropped == 12 and len(tr.spans) == 8
     # FIFO eviction: the ring holds exactly the newest 8, in order
     assert [s.start_step for s in tr.spans] == list(range(12, 20))
@@ -156,8 +156,9 @@ def test_tracer_lifecycle_spans_close_and_never_overlap():
 def test_tracer_chrome_export_is_schema_valid():
     tr = StepTracer()
     tr.request_event("r0", "queued", 0)
-    with tr.dispatch("prefill", 1, predicted_s=1e-3, predicted_j=0.5):
-        pass
+    t = time.perf_counter()
+    tr.record_dispatch("prefill", 1, t, t + 2e-3, predicted_s=1e-3,
+                       predicted_j=0.5)
     tr.request_event("r0", "finished", 2)
     tr.counter_sample(2, [3, 1])
     doc = tr.chrome_trace()
@@ -179,8 +180,8 @@ def test_validate_chrome_trace_flags_malformed_docs():
 def test_rollup_and_format_model_error():
     tr = StepTracer()
     for step in range(3):
-        with tr.dispatch("scan", step, predicted_s=0.5, predicted_j=2.0):
-            pass
+        tr.record_dispatch("scan", step, float(step), step + 0.25,
+                           predicted_s=0.5, predicted_j=2.0)
     report = tr.model_error_report()
     assert set(report) == {"scan"}
     row = report["scan"]
@@ -200,8 +201,7 @@ def test_rollup_and_format_model_error():
 def test_flight_dump_contents(tmp_path):
     tr = StepTracer(capacity=4, dump_dir=str(tmp_path))
     for i in range(6):
-        with tr.dispatch("scan", i):
-            pass
+        tr.record_dispatch("scan", i, float(i), i + 0.5)
     tr.request_event("r0", "queued", 6)
     reg = MetricsRegistry()
     reg.inc("steps", 6)
