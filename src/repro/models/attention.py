@@ -352,9 +352,19 @@ def attend_decode(q, cache: AttnCache, pos, *, window, scale, softcap,
 # exp underflows to 0.0), so garbage there never reaches a real token.
 
 class PagedAttnCache(NamedTuple):
-    # k/v pools, FLAT features: (n_pages, page_size, Kv*hd)
+    # k/v pools, FLAT features: (n_pages, page_size, Kv*hd), or
+    # (n_layers, n_pages, page_size, Kv*hd) for a scanned segment's stack
     k: jnp.ndarray
     v: jnp.ndarray
+
+
+def _at(layer, *idx):
+    """Index of ``idx`` in one layer's pool: the layer's own (P, ps, F)
+    pool when ``layer`` is None, else row ``layer`` of the stacked
+    (L, P, ps, F) pool.  Indexing the stack in ONE scatter or gather is
+    what keeps a scanned segment's pools in their buffer: slicing the
+    layer's slab out first materialises it."""
+    return idx if layer is None else (layer,) + idx
 
 
 def paged_cache_init(cfg, n_pages: int, page_size: int, dtype):
@@ -364,22 +374,24 @@ def paged_cache_init(cfg, n_pages: int, page_size: int, dtype):
 
 
 def paged_cache_update(pool: PagedAttnCache, k_new, v_new, block_tables,
-                       pos):
+                       pos, layer=None):
     """Write step-t k/v into page ``block_tables[b, t//ps]``, slot t%ps.
 
     k_new/v_new (B, 1, Kv, hd); block_tables (B, nmax) int32; pos (B,)
-    int32 per-sequence write position.  Inactive batch slots must point
-    at the null page (their writes collide there harmlessly).
+    int32 per-sequence write position; ``layer`` the row of a stacked
+    pool (``_at``).  Inactive batch slots must point at the null page
+    (their writes collide there harmlessly).
     """
     B = k_new.shape[0]
-    ps = pool.k.shape[1]
+    ps = pool.k.shape[-2]
     k_new = k_new.reshape(B, -1)
     v_new = v_new.reshape(B, -1)
     page = jnp.take_along_axis(block_tables, (pos // ps)[:, None],
                                axis=1)[:, 0]
     slot = pos % ps
-    return PagedAttnCache(k=pool.k.at[page, slot].set(k_new),
-                          v=pool.v.at[page, slot].set(v_new))
+    idx = _at(layer, page, slot)
+    return PagedAttnCache(k=pool.k.at[idx].set(k_new),
+                          v=pool.v.at[idx].set(v_new))
 
 
 def _paged_shard_axes(env, n_pages: int):
@@ -400,7 +412,7 @@ def _paged_shard_axes(env, n_pages: int):
 
 def attend_decode_paged_sharded(q, pool: PagedAttnCache, block_tables,
                                 pos, *, scale, softcap, n_kv: int, env,
-                                axes, impl=None):
+                                axes, impl=None, layer=None):
     """Striped-pool decode via shard_map (pages sharded over "model").
 
     Each stripe owner attends over only the pages whose physical slab
@@ -414,24 +426,29 @@ def attend_decode_paged_sharded(q, pool: PagedAttnCache, block_tables,
     Exactness: stripes a sequence doesn't touch contribute m = NEG_INF,
     and exp(NEG_INF - m_global) underflows to exactly 0.0 — the merge
     adds nothing, matching the single-device masked softmax on the
-    valid slots.
+    valid slots.  A stacked pool stripes its page axis the same way and
+    comes with ``layer`` (replicated): each owner gathers ``(layer, local
+    page)`` from its shard of the stack in one gather.
     """
     from repro.models.moe import _shard_map
     B, _, H, hd = q.shape
-    Pn, ps = pool.k.shape[0], pool.k.shape[1]
+    Pn, ps = pool.k.shape[-3], pool.k.shape[-2]
     Kv = n_kv
     n = env.mesh.shape[axes[0]]
     L = Pn // n
     nmax = block_tables.shape[1]
     T = nmax * ps
 
-    def body(q_l, k_l, v_l, bt_l, pos_l):
+    def body(q_l, k_l, v_l, bt_l, pos_l, *lay):
+        lay = lay[0] if lay else None
         j = jax.lax.axis_index(axes[0])
         local = bt_l - j * L
         mine = (local >= 0) & (local < L)          # (B, nmax)
         safe = jnp.where(mine, local, 0)
         if impl == "pallas":
             from repro.kernels import ops as kops
+            if lay is not None:
+                k_l, v_l = k_l[lay], v_l[lay]
             with jax.named_scope("attend"):
                 acc, m, l = kops.paged_decode_attention(
                     q_l.reshape(B, H, hd), k_l.reshape(L, ps, Kv, hd),
@@ -440,8 +457,8 @@ def attend_decode_paged_sharded(q, pool: PagedAttnCache, block_tables,
                     page_mask=mine.astype(jnp.int32), partials=True)
         else:
             with jax.named_scope("kv_gather"):
-                k = k_l[safe].reshape(B, T, Kv, hd)
-                v = v_l[safe].reshape(B, T, Kv, hd)
+                k = k_l[_at(lay, safe)].reshape(B, T, Kv, hd)
+                v = v_l[_at(lay, safe)].reshape(B, T, Kv, hd)
             with jax.named_scope("attend"):
                 qg = q_l.reshape(B, Kv, H // Kv, hd)
                 s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
@@ -464,18 +481,20 @@ def attend_decode_paged_sharded(q, pool: PagedAttnCache, block_tables,
             return o.astype(q_l.dtype).reshape(B, 1, H * hd)
 
     from jax.sharding import PartitionSpec
-    pool_spec = env.spec("pages", None, None)
+    pool_spec = env.spec(*(None,) * (pool.k.ndim - 3), "pages", None, None)
     repl = PartitionSpec()
+    lay = () if layer is None else (layer,)
     return _shard_map(
         body, mesh=env.mesh,
-        in_specs=(repl, pool_spec, pool_spec, repl, repl),
+        in_specs=(repl, pool_spec, pool_spec, repl, repl) + (repl,) * len(lay),
         out_specs=repl, check_vma=False)(q, pool.k, pool.v,
-                                         block_tables, pos)
+                                         block_tables, pos, *lay)
 
 
 def attend_decode_paged(q, pool: PagedAttnCache, block_tables, pos, *,
-                        scale, softcap, n_kv: int, impl=None):
-    """q (B,1,H,hd); pool pages (P,ps,Kv*hd); pos (B,) int32.
+                        scale, softcap, n_kv: int, impl=None, layer=None):
+    """q (B,1,H,hd); pool pages (P,ps,Kv*hd), or a stack of them with
+    ``layer`` naming the row (``_at``); pos (B,) int32.
 
     Gathers the sequence's pages through the block table and runs the
     same masked decode attention as the dense path — identical arithmetic
@@ -485,29 +504,31 @@ def attend_decode_paged(q, pool: PagedAttnCache, block_tables, pos, *,
     """
     from repro.parallel.sharding import current_env
     env = current_env()
-    axes = _paged_shard_axes(env, pool.k.shape[0])
+    axes = _paged_shard_axes(env, pool.k.shape[-3])
     if axes is not None:
         return attend_decode_paged_sharded(
             q, pool, block_tables, pos, scale=scale, softcap=softcap,
-            n_kv=n_kv, env=env, axes=axes, impl=impl)
+            n_kv=n_kv, env=env, axes=axes, impl=impl, layer=layer)
     B, _, H, hd = q.shape
-    ps = pool.k.shape[1]
+    ps = pool.k.shape[-2]
     Kv = n_kv
     if impl == "pallas":
         from repro.kernels import ops as kops
-        P_ = pool.k.shape[0]
+        P_ = pool.k.shape[-3]
+        kp, vp = (pool.k, pool.v) if layer is None else \
+            (pool.k[layer], pool.v[layer])
         with jax.named_scope("attend"):
             o = kops.paged_decode_attention(
-                q.reshape(B, H, hd), pool.k.reshape(P_, ps, Kv, hd),
-                pool.v.reshape(P_, ps, Kv, hd), block_tables, pos,
+                q.reshape(B, H, hd), kp.reshape(P_, ps, Kv, hd),
+                vp.reshape(P_, ps, Kv, hd), block_tables, pos,
                 scale=scale, softcap=softcap)
             return o.reshape(B, 1, H * hd)
     nmax = block_tables.shape[1]
     T = nmax * ps
     G = H // Kv
     with jax.named_scope("kv_gather"):
-        k = pool.k[block_tables].reshape(B, T, Kv, hd)
-        v = pool.v[block_tables].reshape(B, T, Kv, hd)
+        k = pool.k[_at(layer, block_tables)].reshape(B, T, Kv, hd)
+        v = pool.v[_at(layer, block_tables)].reshape(B, T, Kv, hd)
     with jax.named_scope("attend"):
         qg = q.reshape(B, Kv, G, hd)
         s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
@@ -522,15 +543,15 @@ def attend_decode_paged(q, pool: PagedAttnCache, block_tables, pos, *,
 
 
 def paged_cache_scatter_suffix(pool: PagedAttnCache, k, v, block_row,
-                               start, n_valid):
+                               start, n_valid, layer=None):
     """Scatter a prompt *suffix*'s kv (1,W,Kv,hd) into the pool at
     logical positions start..start+W-1 (a prefix-cache hit: the first
     ``start`` positions already live in shared pages).  Slots at or past
     ``n_valid`` are padding — they are routed to the null page, whose
     garbage is masked by design, so one compile serves every suffix
-    length in a bucket."""
+    length in a bucket.  ``layer`` names the row of a stacked pool."""
     W = k.shape[1]
-    ps = pool.k.shape[1]
+    ps = pool.k.shape[-2]
     k = k.reshape(W, -1)
     v = v.reshape(W, -1)
     t = start + jnp.arange(W)
@@ -539,26 +560,27 @@ def paged_cache_scatter_suffix(pool: PagedAttnCache, k, v, block_row,
     # padding lands there and its garbage is masked to exactly 0
     page = jnp.where(jnp.arange(W) < n_valid, block_row[idx], 0)
     slot = t % ps
-    return PagedAttnCache(k=pool.k.at[page, slot].set(k),
-                          v=pool.v.at[page, slot].set(v))
+    at = _at(layer, page, slot)
+    return PagedAttnCache(k=pool.k.at[at].set(k), v=pool.v.at[at].set(v))
 
 
 def attend_prefill_paged(q, pool: PagedAttnCache, block_row, start, *,
-                         scale, softcap, n_kv: int):
+                         scale, softcap, n_kv: int, layer=None):
     """Suffix-prefill attention: q (1,W,H,hd) holds query positions
     start..start+W-1; keys/values are gathered from the sequence's pages
     (cached prefix + the just-scattered suffix) and masked causally at
     ``j <= start + w`` — one batched dispatch, same arithmetic as the
-    decode path, no new kernel."""
+    decode path, no new kernel.  ``layer`` names the row of a stacked
+    pool."""
     B, W, H, hd = q.shape
-    ps = pool.k.shape[1]
+    ps = pool.k.shape[-2]
     nmax = block_row.shape[0]
     T = nmax * ps
     Kv = n_kv
     G = H // Kv
     with jax.named_scope("kv_gather"):
-        k = pool.k[block_row].reshape(B, T, Kv, hd)
-        v = pool.v[block_row].reshape(B, T, Kv, hd)
+        k = pool.k[_at(layer, block_row)].reshape(B, T, Kv, hd)
+        v = pool.v[_at(layer, block_row)].reshape(B, T, Kv, hd)
     with jax.named_scope("attend"):
         qg = q.reshape(B, W, Kv, G, hd)
         s = jnp.einsum("bwkgd,btkd->bkgwt", qg, k,
@@ -573,17 +595,19 @@ def attend_prefill_paged(q, pool: PagedAttnCache, block_row, start, *,
 
 
 def apply_prefill_paged(p, cfg, x, pool: PagedAttnCache, block_row, start,
-                        n_valid, *, angles):
+                        n_valid, *, angles, layer=None):
     """Paged suffix-prefill path: x (1,W,D) at positions start..;
     scatters the suffix kv then attends over the whole page run.
+    ``layer`` names the row of a stacked pool (``_at``).
     Returns (out (1,W,D'), new pool)."""
     with jax.named_scope("qkv"):
         q, k_new, v_new = _qkv(p, cfg, x, angles)
     with jax.named_scope("kv_append"):
         pool = paged_cache_scatter_suffix(pool, k_new, v_new, block_row,
-                                          start, n_valid)
+                                          start, n_valid, layer)
     o = attend_prefill_paged(q, pool, block_row, start, scale=_scale(cfg),
-                             softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads)
+                             softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads,
+                             layer=layer)
     with jax.named_scope("out_proj"):
         return nn.matmul(o, p["wo"]), pool
 
@@ -593,31 +617,39 @@ def paged_cache_from_prefill(pool: PagedAttnCache, k, v, block_row,
     """Scatter prefill k/v (1,S,Kv,hd) of ONE sequence into the pool.
 
     ``block_row`` (nmax,) int32 is the sequence's block-table row; tokens
-    land at logical slots start..start+S-1.
+    land at logical slots start..start+S-1.  A scanned segment's stack
+    (L,P,ps,F) takes every layer's k/v (L,1,S,Kv,hd) in one scatter at
+    ``(layer, page, slot)``, in place (with the layer axis a window of
+    the scatter instead, the chip's compiler relayouts the whole stack
+    in and out).
     """
-    S = k.shape[1]
-    ps = pool.k.shape[1]
-    k = k.reshape(S, -1)
-    v = v.reshape(S, -1)
+    S = k.shape[-3]
+    ps = pool.k.shape[-2]
     t = start + jnp.arange(S)
-    page = block_row[t // ps]
-    slot = t % ps
-    return PagedAttnCache(k=pool.k.at[page, slot].set(k),
-                          v=pool.v.at[page, slot].set(v))
+    at = (block_row[t // ps], t % ps)
+    if pool.k.ndim == 4:
+        L = pool.k.shape[0]
+        at = (jnp.repeat(jnp.arange(L), S),) + tuple(jnp.tile(i, L)
+                                                     for i in at)
+    k = k.reshape(-1, pool.k.shape[-1])
+    v = v.reshape(-1, pool.v.shape[-1])
+    return PagedAttnCache(k=pool.k.at[at].set(k), v=pool.v.at[at].set(v))
 
 
 def apply_decode_paged(p, cfg, x, pool: PagedAttnCache, block_tables,
-                       pos, *, angles):
+                       pos, *, angles, layer=None):
     """Paged decode path: x (B,1,D), pos (B,). Returns (out, new pool).
-    Its operations carry the scopes ``qkv``, ``kv_append``,
-    ``kv_gather``, ``attend`` and ``out_proj`` in the HLO metadata."""
+    ``layer`` names the row of a stacked pool (``_at``).  Its operations
+    carry the scopes ``qkv``, ``kv_append``, ``kv_gather``, ``attend``
+    and ``out_proj`` in the HLO metadata."""
     with jax.named_scope("qkv"):
         q, k_new, v_new = _qkv(p, cfg, x, angles)
     with jax.named_scope("kv_append"):
-        pool = paged_cache_update(pool, k_new, v_new, block_tables, pos)
+        pool = paged_cache_update(pool, k_new, v_new, block_tables, pos,
+                                  layer)
     o = attend_decode_paged(q, pool, block_tables, pos, scale=_scale(cfg),
                             softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads,
-                            impl=cfg.impl)
+                            impl=cfg.impl, layer=layer)
     with jax.named_scope("out_proj"):
         return nn.matmul(o, p["wo"]), pool
 

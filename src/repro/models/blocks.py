@@ -162,8 +162,9 @@ def apply_decode(p, cfg, kind: str, x, cache, pos, *, angles):
 
 
 def apply_decode_paged(p, cfg, kind: str, x, pool, block_tables, pos, *,
-                       angles):
+                       angles, layer=None):
     """Single-token decode against a paged KV pool. Returns (x, pool).
+    ``layer`` names the row when ``pool`` is a scanned segment's stack.
 
     Only global attention pages cleanly (a sliding-window ring cache and
     the recurrent states are constant-size per sequence — nothing to
@@ -176,7 +177,7 @@ def apply_decode_paged(p, cfg, kind: str, x, pool, block_tables, pos, *,
         h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
     out, pool = attention.apply_decode_paged(p["attn"], cfg, h, pool,
                                              block_tables, pos,
-                                             angles=angles)
+                                             angles=angles, layer=layer)
     with jax.named_scope("out_proj"):
         x = x + _post(p, cfg, "ln1_post", out)
     with jax.named_scope("mlp"):
@@ -187,10 +188,11 @@ def apply_decode_paged(p, cfg, kind: str, x, pool, block_tables, pos, *,
 
 
 def apply_prefill_paged(p, cfg, kind: str, x, pool, block_row, start,
-                        n_valid, *, angles):
+                        n_valid, *, angles, layer=None):
     """Suffix prefill against a paged KV pool (prefix-cache hit): x
     (1,W,D) tokens at positions start..start+W-1 attend the cached
-    prefix through the block row.  Returns (x, pool)."""
+    prefix through the block row; ``layer`` as in
+    :func:`apply_decode_paged`.  Returns (x, pool)."""
     if kind != ATTN:
         raise NotImplementedError(
             f"paged prefill supports global-attention layers only, "
@@ -199,7 +201,7 @@ def apply_prefill_paged(p, cfg, kind: str, x, pool, block_row, start,
         h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
     out, pool = attention.apply_prefill_paged(p["attn"], cfg, h, pool,
                                               block_row, start, n_valid,
-                                              angles=angles)
+                                              angles=angles, layer=layer)
     with jax.named_scope("out_proj"):
         x = x + _post(p, cfg, "ln1_post", out)
     with jax.named_scope("mlp"):

@@ -449,23 +449,50 @@ def init_paged_caches(cfg, n_pages: int, page_size: int):
 
 def paged_from_prefill(cfg, pools, raw_caches, block_row):
     """Scatter ONE sequence's prefill kv (from forward(mode="prefill"),
-    batch 1) into the pools at the pages named by ``block_row``."""
-    segs = make_segments(cfg)
-    out = []
-    for seg, seg_pool, seg_raw in zip(segs, pools, raw_caches):
-        def conv_cycle(cyc_pool, cyc_raw):
-            return tuple(
-                blocks.paged_cache_from_prefill(cfg, seg.kinds[j],
-                                                cyc_pool[j], cyc_raw[j],
-                                                block_row)
-                for j in range(len(seg.kinds)))
+    batch 1) into the pools at the pages named by ``block_row``: one
+    scatter per stacked pool covers all its layers, in place."""
+    return [tuple(blocks.paged_cache_from_prefill(cfg, kind, seg_pool[j],
+                                                  seg_raw[j], block_row)
+                  for j, kind in enumerate(seg.kinds))
+            for seg, seg_pool, seg_raw in zip(make_segments(cfg), pools,
+                                              raw_caches)]
+
+
+def _paged_layers(params, cfg, x, pools, apply):
+    """Run x through every layer against the paged pools, in place.
+
+    ``apply(p, kind, x, pool, layer) -> (x, pool)`` runs one layer.  A
+    scanned segment's layer scan carries the segment's stacked pools
+    with x, and its ``xs`` are the weights and the layer index: each
+    layer scatters into and gathers from the stack at ``(layer, ...)``
+    (``layer`` a traced scalar), so the pools never leave their buffer.
+    Passing the stack as ``xs``/``ys`` instead makes XLA slice every
+    layer's slab out, write it into a fresh stack and copy that back,
+    three whole-pool moves per step.  Unscanned segments hold one pool
+    per layer (``layer`` None).  Returns (x, new pools).
+    """
+    new_pools = []
+    for seg, seg_p, seg_pool in zip(make_segments(cfg), params["segments"],
+                                    pools):
+        def cycle(cyc_p, cyc_pool, x, layer):
+            new_c = []
+            for j, kind in enumerate(seg.kinds):
+                x, c = apply(cyc_p[j], kind, x, cyc_pool[j], layer)
+                new_c.append(c)
+            return x, tuple(new_c)
+
         if seg.scanned:
-            out.append(jax.vmap(conv_cycle)(seg_pool, seg_raw))
+            def scan_body(carry, inp):
+                x, cyc_pool = carry
+                cyc_p, layer = inp
+                return cycle(cyc_p, cyc_pool, x, layer), None
+            (x, new_seg), _ = jax.lax.scan(
+                scan_body, (x, seg_pool),
+                (seg_p, jnp.arange(seg.n_cycles, dtype=jnp.int32)))
         else:
-            out.append(conv_cycle(seg_pool, seg_raw))
-        # vmap over the scan-stacked layer dim: same block row, each
-        # layer's own pool/raw slice
-    return out
+            x, new_seg = cycle(seg_p, seg_pool, x, None)
+        new_pools.append(new_seg)
+    return x, new_pools
 
 
 def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
@@ -480,28 +507,11 @@ def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
         positions = pos[:, None].astype(jnp.int32)
         angles = _angles(cfg, positions)
 
-    segs = make_segments(cfg)
-    new_pools = []
-    for seg, seg_p, seg_pool in zip(segs, params["segments"], pools):
-        def cycle_decode(cyc_p, cyc_pool, x):
-            new_c = []
-            for j, kind in enumerate(seg.kinds):
-                x, c = blocks.apply_decode_paged(cyc_p[j], cfg, kind, x,
-                                                 cyc_pool[j], block_tables,
-                                                 pos, angles=angles)
-                new_c.append(c)
-            return x, tuple(new_c)
+    def layer_decode(p, kind, x, pool, layer):
+        return blocks.apply_decode_paged(p, cfg, kind, x, pool, block_tables,
+                                         pos, angles=angles, layer=layer)
 
-        if seg.scanned:
-            def scan_body(x, inp):
-                cyc_p, cyc_pool = inp
-                x, new_c = cycle_decode(cyc_p, cyc_pool, x)
-                return x, new_c
-            x, new_seg = jax.lax.scan(scan_body, x, (seg_p, seg_pool))
-        else:
-            x, new_seg = cycle_decode(seg_p, seg_pool, x)
-        new_pools.append(new_seg)
-
+    x, new_pools = _paged_layers(params, cfg, x, pools, layer_decode)
     with jax.named_scope("head"):
         h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
         return head_logits(params, cfg, h), new_pools
@@ -550,28 +560,12 @@ def _suffix_forward_paged(params, cfg, tokens, pools, block_row, start,
         positions = jnp.broadcast_to(positions, (B, W))
         angles = _angles(cfg, positions)
 
-    segs = make_segments(cfg)
-    new_pools = []
-    for seg, seg_p, seg_pool in zip(segs, params["segments"], pools):
-        def cycle_apply(cyc_p, cyc_pool, x):
-            new_c = []
-            for j, kind in enumerate(seg.kinds):
-                x, c = blocks.apply_prefill_paged(
-                    cyc_p[j], cfg, kind, x, cyc_pool[j], block_row,
-                    start, n_valid, angles=angles)
-                new_c.append(c)
-            return x, tuple(new_c)
+    def layer_prefill(p, kind, x, pool, layer):
+        return blocks.apply_prefill_paged(p, cfg, kind, x, pool, block_row,
+                                          start, n_valid, angles=angles,
+                                          layer=layer)
 
-        if seg.scanned:
-            def scan_body(x, inp):
-                cyc_p, cyc_pool = inp
-                x, new_c = cycle_apply(cyc_p, cyc_pool, x)
-                return x, new_c
-            x, new_seg = jax.lax.scan(scan_body, x, (seg_p, seg_pool))
-        else:
-            x, new_seg = cycle_apply(seg_p, seg_pool, x)
-        new_pools.append(new_seg)
-    return x, new_pools
+    return _paged_layers(params, cfg, x, pools, layer_prefill)
 
 
 def prefill_suffix_paged(params, cfg, tokens, pools, block_row, start,

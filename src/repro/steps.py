@@ -71,7 +71,11 @@ def constrain_paged_pools(pools):
     to a different (e.g. replicated) layout — the device-side analogue
     of ``StripedStore.write`` re-pinning its slab.  The page axis is
     third-from-last in both unstacked (P, ps, F) and scan-stacked
-    (C, P, ps, F) leaves.
+    (C, P, ps, F) leaves.  The pools reach it updated in place: a
+    scanned segment's stack rides the layer scan's carry and each layer
+    scatters into it at ``(layer, page, slot)`` (``lm._paged_layers``),
+    so with the pools donated the output aliases the input buffer and
+    no step copies a pool.
     """
     env = current_env()
     if env is None:
@@ -120,7 +124,8 @@ def make_paged_serve_step(cfg: ModelConfig):
     """One continuous-batch paged decode step (greedy sampling).
 
     (params, tokens (B,1), pools, block_tables (B,nmax), pos (B,)) ->
-    (next tokens (B,1), logits, updated pools).
+    (next tokens (B,1), logits, updated pools).  Jit with the pools
+    donated: the layer scan updates them in place.
     """
     @_scoped
     def serve_paged(params, tokens, pools, block_tables, pos):
@@ -140,7 +145,8 @@ def make_paged_suffix_prefill(cfg: ModelConfig):
     Only the uncached suffix runs through the model — one dispatch,
     attending the shared-prefix KV through the block row.  Jit with the
     pools donated; the padded width W is the only retrace axis (the
-    engine buckets it to powers of two).
+    engine buckets it to powers of two).  Every layer writes the suffix
+    kv into the donated pools in place, through the layer scan's carry.
     """
     @_scoped
     def suffix_prefill(params, tokens, pools, block_row, start, n_valid):
@@ -162,8 +168,9 @@ def make_chunk_prefill(cfg: ModelConfig):
     prefill is bit-identical to monolithic: each chunk writes exactly
     the KV a single prefill would have written at those positions, and
     only the final chunk's logits are read (the first generated token).
-    Jit with the pools donated; the padded width W is the only retrace
-    axis (the engine buckets it to powers of two), so a heavy-tailed
+    Jit with the pools donated (each layer scatters into them in place,
+    through the layer scan's carry); the padded width W is the only
+    retrace axis (the engine buckets it to powers of two), so a heavy-tailed
     prompt-length distribution compiles O(log max_chunk) kernels instead
     of one per length.
     """
@@ -184,8 +191,9 @@ def make_verify_window(cfg: ModelConfig):
     position, updated pools).  Reuses the suffix-prefill layer path
     (``attention.apply_prefill_paged``) so scoring K+1 positions costs
     one model pass with decode-identical arithmetic.  Jit with the pools
-    donated; the padded width W is the only retrace axis (the engine
-    buckets it to powers of two).
+    donated (updated in place through the layer scan's carry); the
+    padded width W is the only retrace axis (the engine buckets it to
+    powers of two).
     """
     @_scoped
     def verify_window(params, tokens, pools, block_row, start, n_valid):
@@ -222,7 +230,8 @@ def make_spec_draft_verify(cfg: ModelConfig):
     4. the accepted tokens are appended to the slot's history row, so
        the next window drafts from an already-current device history.
 
-    Jit with history and the pools donated; the host pulls only
+    Jit with history and the pools donated (the verify pass updates the
+    pools in place through the layer scan's carry); the host pulls only
     ``(emitted, n_emit, m)`` — one d2h event per verify.
     """
     from repro.serving.spec_decode import device_propose
@@ -289,7 +298,11 @@ def make_paged_serve_scan(cfg: ModelConfig):
      active (B,), k) -> (emitted (B,K), last tokens (B,1), pos (B,),
     updated pools).  ``k`` is the scan length — jit with
     ``static_argnames=("k",)`` and the pools donated; one dispatch and
-    one host sync then cover K decode steps instead of one.
+    one host sync then cover K decode steps instead of one.  The pools
+    stay in the donated buffer for the whole window: the window scan
+    carries them into the layer scan's carry, where every layer's
+    append is one scatter into the stack and its gather reads the
+    stack at ``(layer, block_tables)``.
     """
     @_scoped
     def serve_scan(params, tokens, pools, block_tables, pos, active, *,

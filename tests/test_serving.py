@@ -205,6 +205,178 @@ def test_paged_decode_ignores_null_page_garbage():
     assert jnp.array_equal(o1, o2)
 
 
+# --- the layer loop writes the stacked pools in place -------------------------
+def _layer_list(cfg, params, pools):
+    """Per-layer (weights, (P, ps, F) pool) pairs, in layer order, cut
+    out of the segments' stacks."""
+    from repro.models import lm
+    out = []
+    for seg, seg_p, seg_pool in zip(lm.make_segments(cfg),
+                                    params["segments"], pools):
+        for c in range(seg.n_cycles):
+            cut = (lambda t, c=c: jax.tree.map(lambda a: a[c], t)) \
+                if seg.scanned else (lambda t: t)
+            out += [(cut(seg_p[j]), cut(seg_pool[j]))
+                    for j in range(len(seg.kinds))]
+    return out
+
+
+def _restack(cfg, pools, layer_pools):
+    from repro.models import lm
+    out, it = [], iter(layer_pools)
+    for seg in lm.make_segments(cfg):
+        cyc = [[next(it) for _ in seg.kinds] for _ in range(seg.n_cycles)]
+        if seg.scanned:
+            out.append(tuple(jax.tree.map(lambda *a: jnp.stack(a), *col)
+                             for col in zip(*cyc)))
+        else:
+            out.append(tuple(cyc[0]))
+    return out
+
+
+def _ref_layer(p, cfg, x, pool, angles, kv_step):
+    """One layer of the paged programs from the per-layer attention
+    functions: ``kv_step(q, k, v, pool) -> (attention out, pool)``."""
+    from repro.models import blocks, modules as nn
+    from repro.models.attention import _qkv
+    h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
+    q, k, v = _qkv(p["attn"], cfg, h, angles)
+    o, pool = kv_step(q, k, v, pool)
+    x = x + blocks._post(p, cfg, "ln1_post", nn.matmul(o, p["attn"]["wo"]))
+    h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
+    out2, _ = blocks._ffn_part(p, cfg, h2)
+    return x + blocks._post(p, cfg, "ln2_post", out2), pool
+
+
+def _ref_forward(params, cfg, pools, x, angles, kv_step):
+    """Every layer in turn on its own pool.  The barrier rounds x to its
+    dtype between layers, as the program's layer scan does: unrolled,
+    XLA may carry it across at a higher precision."""
+    new = []
+    for p, pool in _layer_list(cfg, params, pools):
+        x, pool = _ref_layer(p, cfg, x, pool, angles, kv_step)
+        x = jax.lax.optimization_barrier(x)
+        new.append(pool)
+    return x, _restack(cfg, pools, new)
+
+
+def _ref_window(params, cfg, tokens, pools, bt, pos, active, k):
+    """serve_scan's window, layer by layer on per-layer pools, from
+    ``paged_cache_update`` and ``attend_decode_paged``."""
+    from repro.models import attention as A, lm, modules as nn
+    toks = []
+    for _ in range(k):
+        def kv_step(q, kn, vn, pool):
+            pool = A.paged_cache_update(pool, kn, vn, bt, pos)
+            return A.attend_decode_paged(
+                q, pool, bt, pos, scale=A._scale(cfg),
+                softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads), pool
+        x = lm.embed_tokens(params, cfg, tokens)
+        angles = lm._angles(cfg, pos[:, None])
+        x, pools = _ref_forward(params, cfg, pools, x, angles, kv_step)
+        h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
+        nxt = jnp.argmax(lm.head_logits(params, cfg, h), -1).astype(
+            jnp.int32)
+        tokens = jnp.where(active[:, None] > 0, nxt, tokens)
+        pos = pos + active
+        toks.append(tokens[:, 0])
+    return jnp.stack(toks, 1), pools
+
+
+def _ref_chunk(params, cfg, tokens, pools, row, start, n_valid):
+    """chunk_prefill layer by layer, from ``paged_cache_scatter_suffix``
+    and ``attend_prefill_paged``."""
+    from repro.models import attention as A, lm, modules as nn
+    W = tokens.shape[1]
+
+    def kv_step(q, kn, vn, pool):
+        pool = A.paged_cache_scatter_suffix(pool, kn, vn, row, start,
+                                            n_valid)
+        return A.attend_prefill_paged(
+            q, pool, row, start, scale=A._scale(cfg),
+            softcap=cfg.attn_softcap, n_kv=cfg.n_kv_heads), pool
+    x = lm.embed_tokens(params, cfg, tokens)
+    angles = lm._angles(cfg, (start + jnp.arange(W, dtype=jnp.int32))[None])
+    x, pools = _ref_forward(params, cfg, pools, x, angles, kv_step)
+    h = nn.rmsnorm(jnp.take(x, n_valid - 1, axis=1)[:, None],
+                   params["final_norm"]["scale"], cfg.norm_eps)
+    return lm.head_logits(params, cfg, h), pools
+
+
+def _written(cfg, pools, pages, slots):
+    """Boolean mask over every pool leaf: True at every layer's
+    (page, slot) pairs listed."""
+    mask = np.zeros(jax.tree.leaves(pools)[0].shape[-3:-1], bool)
+    mask[np.asarray(pages), np.asarray(slots)] = True
+    return jax.tree.map(
+        lambda a: np.broadcast_to(mask[..., None], a.shape), pools)
+
+
+def _assert_in_place(cfg, before, after, ref, mask):
+    for b, a, r, m in zip(*(jax.tree.leaves(t)
+                            for t in (before, after, ref, mask))):
+        b, a, r = (np.asarray(t.astype(jnp.float32)) for t in (b, a, r))
+        np.testing.assert_array_equal(a[~m], b[~m])   # untouched entries
+        np.testing.assert_array_equal(a[m], r[m])     # the writes
+        assert not np.array_equal(a[m], b[m])
+
+
+@pytest.mark.parametrize("n_layers", [2, 1], ids=["scanned", "unscanned"])
+def test_paged_programs_write_pools_in_place_like_per_layer_reference(
+        n_layers):
+    """One ``serve_scan`` window and one ``chunk_prefill`` on random
+    pools: every entry outside the written (layer, page, slot) set is
+    bitwise unchanged, and the written entries, the emitted tokens and
+    the chunk's logits equal a layer-by-layer reference on per-layer
+    pools.  Two layers make one scanned segment (the stacked pools ride
+    the layer scan's carry); one layer is an unscanned segment."""
+    from repro import steps
+    from repro.models import lm
+    cfg, params = get_tiny_model()
+    if n_layers != cfg.n_layers:
+        cfg = cfg.replace(n_layers=n_layers)
+        params = lm.init_params(jax.random.PRNGKey(1), cfg)
+    assert lm.make_segments(cfg)[0].scanned == (n_layers > 1)
+    B, ps, nmax, k = 3, 4, 4, 3
+    n_pages = B * nmax + 1
+    ks = iter(jax.random.split(KEY, 8))
+    pools = jax.tree.map(
+        lambda a: jax.random.normal(next(ks), a.shape).astype(a.dtype),
+        lm.init_paged_caches(cfg, n_pages, ps))
+    # slot 2 is idle: a null-page row, held at position 0
+    bt = np.zeros((B, nmax), np.int32)
+    bt[:2] = 1 + np.random.default_rng(0).permutation(2 * nmax).reshape(
+        2, nmax)
+    bt = jnp.asarray(bt)
+    pos = jnp.array([5, 2, 0], jnp.int32)
+    active = jnp.array([1, 1, 0], jnp.int32)
+    tokens = jnp.array([[7], [11], [0]], jnp.int32)
+
+    scan = jax.jit(steps.make_paged_serve_scan(cfg), static_argnames=("k",))
+    toks, _, _, got = scan(params, tokens, pools, bt, pos, active, k=k)
+    want_toks, want = jax.jit(_ref_window, static_argnums=(1, 7))(
+        params, cfg, tokens, pools, bt, pos, active, k)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(want_toks))
+    p = np.asarray(pos)[:, None] + np.arange(k) * np.asarray(active)[:, None]
+    pages = np.take_along_axis(np.asarray(bt), p // ps, 1)
+    _assert_in_place(cfg, pools, got, want,
+                     _written(cfg, pools, pages, p % ps))
+
+    W, start, n_valid = 8, 6, 5
+    row = bt[0]
+    chunk_toks = jax.random.randint(next(ks), (1, W), 0, cfg.vocab_size)
+    logits, got = jax.jit(steps.make_chunk_prefill(cfg))(
+        params, chunk_toks, pools, row, start, n_valid)
+    want_logits, want = jax.jit(_ref_chunk, static_argnums=(1,))(
+        params, cfg, chunk_toks, pools, row, start, n_valid)
+    np.testing.assert_array_equal(np.asarray(logits),
+                                  np.asarray(want_logits))
+    t = start + np.arange(W)
+    pages = np.where(np.arange(W) < n_valid, np.asarray(row)[t // ps], 0)
+    _assert_in_place(cfg, pools, got, want, _written(cfg, pools, pages,
+                                                     t % ps))
+
+
 # --- engine: paged and dense produce identical tokens -------------------------
 
 

@@ -11,7 +11,9 @@ library; under several test workers only the worker given this file
 loads it.  The persistent compile cache is off around these compiles: a
 compile for a described chip is written to it but cannot be read back.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,3 +127,85 @@ def test_decode_window_two_layers_fits(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 16e9, used
+
+
+# qwen3-1.7b as the serving benchmark runs it: bf16 weights, batch 16,
+# page 16, requests up to 2048 tokens, the pool the exact worst case
+SB, SNMAX = 16, 128
+
+
+def _instructions(text):
+    """(name, result shape, opcode, called computation) of every
+    instruction, and each computation's root opcode."""
+    inst, roots, comp = [], {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(ROOT )?%(\S+) = (\S+) ([\w-]+)\(", line)
+        if not m:
+            continue
+        if m.group(1):
+            roots[comp] = m.group(4)
+        calls = re.search(r"calls=%([^,\s]+)", line)
+        inst.append((m.group(2), m.group(3).split("{")[0], m.group(4),
+                     calls and calls.group(1)))
+    return inst, roots
+
+
+@pytest.mark.parametrize("program",
+                         ["serve_scan", "chunk_prefill", "prefill_paged"])
+def test_paged_programs_update_pools_in_place(one_chip, program):
+    """The decode window (k=8), the 512-token chunk prefill and the
+    512-token monolithic prefill at full width, pools donated as the
+    engine donates them: no instruction but the in-place scatters (a
+    scatter, or a fusion whose root is one) and free views (bitcasts)
+    produces a whole stacked pool or a whole layer slab of it, and the
+    temporaries stay under one stacked pool.  Passing the stacks
+    through the layer scan as xs/ys instead costs a slice of each
+    layer's slab, a write into a fresh stack and a copy of the stack
+    back, every step."""
+    from repro import steps
+    from repro.configs import get_config
+    from repro.models import lm
+    cfg = get_config("qwen3-1.7b").replace(param_dtype="bfloat16")
+    n_pages = SB * SNMAX + 1
+    to = lambda t: jax.tree.map(                          # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = to(jax.eval_shape(lambda k: lm.init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pools = to(jax.eval_shape(lambda: lm.init_paged_caches(cfg, n_pages, PS)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,   # noqa: E731
+                                          sharding=one_chip)
+    if program == "serve_scan":
+        compiled = jax.jit(steps.make_paged_serve_scan(cfg),
+                           static_argnames=("k",), donate_argnums=(2,)).lower(
+            params, i32(SB, 1), pools, i32(SB, SNMAX), i32(SB), i32(SB),
+            k=8).compile()
+    elif program == "chunk_prefill":
+        compiled = jax.jit(steps.make_chunk_prefill(cfg),
+                           donate_argnums=(2,)).lower(
+            params, i32(1, 512), pools, i32(SNMAX), i32(), i32()).compile()
+    else:
+        compiled = jax.jit(steps.make_paged_prefill_step(cfg),
+                           donate_argnums=(2,)).lower(
+            params, i32(1, 512), pools, i32(SNMAX)).compile()
+    stack = jax.tree.leaves(pools)[0]
+
+    def size(shape):                 # elements of a bf16 result, else 0
+        m = re.fullmatch(r"bf16\[([\d,]+)\]", shape)
+        return m and math.prod(map(int, m.group(1).split(",")))
+    inst, roots = _instructions(compiled.as_text())
+    scatter = lambda op, calls: "scatter" in (op, roots.get(calls))  # noqa
+    makes = [(name, op) for name, shape, op, calls in inst
+             if size(shape) in (stack.size, stack.size // stack.shape[0])
+             and not scatter(op, calls)
+             and op not in ("parameter", "get-tuple-element", "bitcast")]
+    scatters = [name for name, shape, op, calls in inst
+                if size(shape) == stack.size and scatter(op, calls)]
+    assert not makes, makes
+    assert len(scatters) >= 2, scatters           # k and v, in place
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < stack.size * stack.dtype.itemsize, temp
