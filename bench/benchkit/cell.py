@@ -6,6 +6,9 @@ metric is a file of its own, found by the name ``BENCHMARK.json`` gives:
 * ``bench/configs/<config>.json``: the configuration (published keys,
   the engine options, the check's limit) and, named by its
   ``reference`` key, the plain reference beside it;
+* ``bench/benchkit/models/<model_type>.py``: the configuration's model
+  family, named by its published ``model_type`` (see
+  :mod:`benchkit.engine`);
 * ``bench/traffic/<traffic>.json``: the traffic mix, read by
   :mod:`benchkit.traffic`;
 * ``bench/metrics/<metric>.py``: the reader of one per-layer metric,
@@ -143,30 +146,36 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float,
         jax.config.update("jax_compilation_cache_max_size", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    from benchkit import engine
     cell = find_cell(bench, cell_name)
+    engine.stage_on_host(cell["chips"])
     devices = jax.devices()
     if devices[0].platform != platform or len(devices) < cell["chips"]:
         raise NoChip(f"JAX found {len(devices)} {devices[0].platform} "
                      f"device(s); the cell needs {cell['chips']} "
                      f"{platform} chip(s)")
-    from benchkit import check, counting, engine, harness, traffic, trace as tr
+    from benchkit import check, counting, harness, traffic, trace as tr
     kind = devices[0].device_kind
     peak = counting.peaks(kind) if platform == "tpu" else None
     cfg_file = config_file or config_path(bench, cell["config"])
     cfg = load_json(cfg_file)
     ref = load_module(cfg_file.parent / cfg["reference"])
     mix = mix or load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
-    shape = counting.Shape.of(cfg)
+    shape = engine.work(cfg)
     vocab = cfg["vocab_size"]
+    used = devices[:cell["chips"]]
 
     compiles = harness.CompileCounter()
     marks = [("start", time.perf_counter())]
-    weights = jax.block_until_ready(ref.make_weights(cfg, seed))
+    # on several chips one replica per chip, shared by the program's
+    # parameters and the check
+    weights = jax.block_until_ready(engine.replicated(
+        ref.make_weights(cfg, seed), used))
     marks.append(("weights", time.perf_counter()))
     mcfg = engine.model_config(cfg)
-    params = engine.program_params(weights, mcfg)
+    params = engine.program_params(cfg, weights, mcfg)
     sched = traffic.build(mix, seed, seconds + SCHEDULE_MARGIN_S, vocab)
-    eng = engine.build(cfg, mcfg, params, sched.max_tokens)
+    eng = engine.build(cfg, mcfg, params, sched.max_tokens, used)
     marks.append(("engine", time.perf_counter()))
     engine.warm(eng, sched, vocab)
     marks.append(("warm", time.perf_counter()))
@@ -182,7 +191,6 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float,
         setup_s = out.t0 - t_start
         marks.append(("warm-in", out.t0))
         jax.block_until_ready(eng.pools)
-        used = devices[:cell["chips"]]
         mem = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in used]
         reduced = None
         if trace:
@@ -194,7 +202,11 @@ def run(bench: dict, cell_name: str, seed: int, seconds: float,
             [("", t_start)] + marks, marks))
         + f"; persistent cache {compiles.cache}")
     log(f"[bench] compiles_in_window {out.compiles_in_window} "
-        f"preemptions {out.preemptions} requests {len(out.records)}")
+        f"preemptions {out.preemptions} requests {len(out.records)}; "
+        f"prompt tokens in the window cached "
+        f"{out.counters['prefill_tokens_cached']} computed "
+        f"{out.counters['prefill_tokens']}; cache evictions "
+        f"{eng.cache.stats.evictions if eng.cache is not None else 0}")
     log("[bench] host: " + host_summary(out))
     engine_opts = dict(cfg["engine"])
     max_len = eng.max_len

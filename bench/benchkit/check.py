@@ -63,21 +63,35 @@ def widest_gap(ref, weights, cfg: dict, seqs, seq_len: int, n_read: int,
                control: bool = False) -> float:
     """Widest gap over ``seqs``, a list of (prompt, served tokens), each
     padded to ``seq_len`` positions and ``n_read`` served tokens (one
-    compiled program for every sequence)."""
+    compiled program for every sequence).  Where ``weights`` has a whole
+    copy on each of several chips, the sequences are dealt out over the
+    chips in turn."""
     import jax.numpy as jnp
     fn = _scorer(ref, _freeze(cfg), seq_len, n_read, control)
-    worst = -np.inf
-    for prompt, served in seqs:
+    copies = _copies(weights)
+    gaps = []
+    for i, (prompt, served) in enumerate(seqs):
         served = np.asarray(served, np.int32)
         toks = np.zeros(seq_len, np.int32)
         full = np.concatenate([prompt, served[:-1]])
         toks[:len(full)] = full
         pad = np.zeros(n_read, np.int32)
         pad[:len(served)] = served
-        g = float(fn(weights, jnp.asarray(toks), len(prompt) - 1,
-                     jnp.asarray(pad), len(served)))
-        worst = max(worst, g)
-    return worst
+        gaps.append(fn(copies[i % len(copies)], jnp.asarray(toks),
+                       len(prompt) - 1, jnp.asarray(pad), len(served)))
+    return max((float(g) for g in gaps), default=-np.inf)
+
+
+def _copies(weights) -> list:
+    """One whole copy of ``weights`` per device that holds one."""
+    import jax
+    devices = sorted(jax.tree.leaves(weights)[0].sharding.device_set,
+                     key=lambda d: d.id)
+    if len(devices) == 1:
+        return [weights]
+    return [jax.tree.map(lambda a: next(
+        s.data for s in a.addressable_shards if s.device == d), weights)
+        for d in devices]
 
 
 def _freeze(cfg: dict):

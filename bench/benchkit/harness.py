@@ -149,8 +149,10 @@ class GcPauses:
 
 
 class Loop:
-    def __init__(self, eng, schedule: Schedule, shape: counting.Shape,
+    def __init__(self, eng, schedule: Schedule, shape,
                  trace_dir: Optional[str] = None):
+        """``shape`` is the configuration's work count (see
+        :mod:`benchkit.counting`)."""
         self.eng = eng
         self.schedule = schedule
         self.shape = shape

@@ -6,9 +6,10 @@ and operations (``XLA Ops``) with their start and end in nanoseconds,
 and the harness's own host spans (names starting ``bench.``).
 :func:`reduce` turns that record, clipped to the traced interval, into
 busy and idle time (busy: the union of the operations' intervals),
-device time per program, the operations that took most
-time by their self time (a ``while`` less its body) and the longest idle
-gaps, each named after the harness span that was open across it.
+device time per program, the device time per program spent in
+cross-chip collective operations, the operations that took most time by
+their self time (a ``while`` less its body) and the longest idle gaps,
+each named after the harness span that was open across it.
 """
 from __future__ import annotations
 
@@ -22,6 +23,9 @@ SPAN_PREFIX = "bench."
 DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+$")
 TRACED_SPAN = "bench.traced"          # the harness opens it around the
                                       # whole traced interval
+# operations that move data between chips, by their HLO name
+COLLECTIVE = re.compile(
+    r"all-reduce|all-gather|collective-permute|reduce-scatter|all-to-all")
 
 
 def extract(path: str) -> dict:
@@ -119,6 +123,7 @@ def reduce(rec: dict, top: int = 10) -> dict:
         raise ValueError("no device plane in the trace")
     busy = 0.0
     programs: Dict[str, float] = {}
+    collective: Dict[str, float] = {}
     ops: Dict[str, float] = {}
     gaps: List[Tuple[str, float]] = []
     for i, dev in enumerate(devs):
@@ -137,6 +142,8 @@ def reduce(rec: dict, top: int = 10) -> dict:
                 and by_start[j][2] >= s else "?"
             key = f"{prog}/{n}"
             ops[key] = ops.get(key, 0.0) + own / 1e9
+            if COLLECTIVE.search(n):
+                collective[prog] = collective.get(prog, 0.0) + own / 1e9
         if i == 0:
             edges = [t0] + [x for seg in segs for x in seg] + [t1]
             for a, b in zip(edges[::2], edges[1::2]):
@@ -148,6 +155,7 @@ def reduce(rec: dict, top: int = 10) -> dict:
         "busy_s": busy / n,
         "idle_share": 1.0 - busy / n / window,
         "program_s": {k: v / n for k, v in programs.items()},
+        "collective_s": {k: v / n for k, v in collective.items()},
         "device_ops": [[k, v / n] for k, v in sorted(
             ops.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": [[k, v] for k, v in sorted(

@@ -11,7 +11,10 @@ the output head (the embedding matrix when ``tie_word_embeddings``).
 
 It runs in float32 with every matrix product at ``HIGHEST`` precision,
 imports nothing of the program, and scans over the layers so that one
-layer's weights are widened at a time.  ``quant="fp8"`` is the control:
+layer's weights are widened at a time.  Attention is computed in blocks
+of ``Q_BLOCK`` query rows, each over all of its keys, so that the scores
+of a long sequence never exist whole (16,896 positions would take 18 GB
+a layer).  ``quant="fp8"`` is the control:
 the same pass with every weight matrix rounded to float8 (e4m3) with a
 scale per output channel.
 """
@@ -22,6 +25,7 @@ import jax.numpy as jnp
 
 HI = jax.lax.Precision.HIGHEST
 MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+Q_BLOCK = 512
 
 
 def make_weights(cfg: dict, seed: int) -> dict:
@@ -83,7 +87,30 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def hidden(weights: dict, cfg: dict, tokens, quant: str = "none"):
+def attend(q, k, v, block: int = Q_BLOCK):
+    """Causal softmax attention at scale 1/sqrt(head_dim) of queries
+    (S, Kv, G, hd) over keys and values (S, Kv, hd), ``block`` query rows
+    at a time; returns (S, Kv, G, hd)."""
+    S, Kv, G, hd = q.shape
+    nb = -(-S // block)
+    qb = jnp.pad(q, ((0, nb * block - S), (0, 0), (0, 0), (0, 0))
+                 ).reshape(nb, block, Kv, G, hd)
+    rows = jnp.arange(nb * block).reshape(nb, block)
+    keys = jnp.arange(S)
+
+    def one(args):
+        qi, ri = args
+        s = jnp.einsum("skgd,tkd->kgst", qi, k, precision=HI) * hd ** -0.5
+        s = jnp.where(keys[None, None, None, :] <= ri[None, None, :, None],
+                      s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("kgst,tkd->skgd", p, v, precision=HI)
+
+    return jax.lax.map(one, (qb, rows)).reshape(nb * block, Kv, G, hd)[:S]
+
+
+def hidden(weights: dict, cfg: dict, tokens, quant: str = "none",
+           block: int = Q_BLOCK):
     """Final-normed hidden states (S, d) of one causal sequence."""
     H, Kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
                  cfg["head_dim"])
@@ -97,7 +124,6 @@ def hidden(weights: dict, cfg: dict, tokens, quant: str = "none"):
     emb = weights["embed"]
     x = (_fp8(emb.astype(jnp.float32), -1) if quant == "fp8"
          else emb.astype(jnp.float32))[tokens]
-    causal = pos[None, :] <= pos[:, None]
 
     def layer(x, lw):
         w = {k: widen(lw[k]) for k in MATRICES}
@@ -107,11 +133,8 @@ def hidden(weights: dict, cfg: dict, tokens, quant: str = "none"):
         v = jnp.matmul(h, w["wv"], precision=HI).reshape(S, Kv, hd)
         q = _rope(_rms(q, lw["q_norm"], eps), pos, theta)
         k = _rope(_rms(k, lw["k_norm"], eps), pos, theta)
-        q = q.reshape(S, Kv, H // Kv, hd)
-        s = jnp.einsum("skgd,tkd->kgst", q, k, precision=HI) * hd ** -0.5
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("kgst,tkd->skgd", p, v, precision=HI).reshape(S, H * hd)
+        o = attend(q.reshape(S, Kv, H // Kv, hd), k, v, block
+                   ).reshape(S, H * hd)
         x = x + jnp.matmul(o, w["wo"], precision=HI)
         h = _rms(x, lw["ln2"], eps)
         g = jnp.matmul(h, w["w_gate"], precision=HI)

@@ -1,5 +1,5 @@
 """The work counted from shapes, against a qwen3-1.7b decode step worked
-by hand, and the peak table."""
+by hand, and the peak table; the decode roofline's reading."""
 import sys
 from pathlib import Path
 
@@ -8,9 +8,11 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-from benchkit import cell, counting  # noqa: E402
+from types import SimpleNamespace  # noqa: E402
 
-QWEN = counting.Shape.of(cell.load_json(BENCH / "configs" / "qwen3-1.7b.json"))
+from benchkit import cell, counting, engine, harness  # noqa: E402
+
+QWEN = engine.work(cell.load_json(BENCH / "configs" / "qwen3-1.7b.json"))
 
 
 def test_qwen3_decode_step_by_hand():
@@ -54,3 +56,36 @@ def test_unknown_device_is_an_error():
     with pytest.raises(KeyError, match="no published peaks"):
         counting.peaks("cpu")
     assert counting.peaks("TPU v5 lite")["flops_bf16"] == 197e12
+
+
+def test_qwen3_work_counts_are_pinned():
+    """The integers the dense counting gave before model families became
+    modules of their own."""
+    assert QWEN.weight_bytes == 3440902144
+    assert QWEN.kv_bytes_per_token == 114688
+    assert [QWEN.token_flops(c) for c in (1, 512, 2048)] == [
+        2818801664, 2936012800, 3288334336]
+    assert QWEN.head_flops == 622329856
+    assert QWEN.prefill_flops(0, 512) == 1473232502784
+    assert QWEN.prefill_flops(1024, 512) == 1593491587072
+    assert counting.prefill(QWEN, 1024, 512, logits=True) == 1594113916928
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_decode_roofline_divides_the_least_time_by_the_chips(chips):
+    """The decode work is counted once; its least time on ``chips`` chips
+    is the one-chip least time over ``chips``, against the program's
+    device time averaged over the chips."""
+    reader = cell.load_module(BENCH / "metrics" / "decode_roofline.py")
+    peak = counting.peaks("TPU v5 lite")
+    work = harness.Work(decode_steps=10)
+    for ctx in (100, 2000):
+        f, b = counting.decode_step(QWEN, [ctx])
+        work.decode_flops += 10 * f
+        work.decode_kv_bytes += 10 * (b - QWEN.weight_bytes)
+    ctx = SimpleNamespace(
+        trace={"program_s": {"serve_scan": 0.5}}, out=SimpleNamespace(
+            work=work), shape=QWEN, peak=peak, chips=chips)
+    least = max(work.decode_flops / 197e12,
+                (10 * 3440902144 + work.decode_kv_bytes) / 819e9)
+    assert reader.read(ctx) == pytest.approx(100 * least / chips / 0.5)

@@ -1,18 +1,22 @@
 """Every file the benchmark names loads, and BENCHMARK.json keeps to the
 benchmark's contract (names, units, bounds, cells, paths)."""
+import dataclasses
 import json
 import re
 import sys
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+DATA = Path(__file__).resolve().parent / "data"
 sys.path[:0] = [str(BENCH), str(ROOT / "src")]
 
 from benchkit import cell, counting, engine, traffic  # noqa: E402
+from benchkit import models  # noqa: E402
 
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -41,11 +45,20 @@ def test_config_file_loads(c):
                    for k in c["reduced"])
     ref = cell.load_module((ROOT / c["file"]).parent / cfg["reference"])
     assert callable(ref.make_weights) and callable(ref.hidden)
+    family = engine.adapter(cfg)
+    assert family.__name__ == f"benchkit.models.{cfg['model_type']}"
     mcfg = engine.model_config(cfg)
     assert mcfg.d_model == cfg["hidden_size"]
     assert mcfg.n_layers == cfg["num_hidden_layers"]
     assert cfg["check"]["logit_gap_limit"] > 0
-    assert counting.Shape.of(cfg).layer_params > 0
+    work = engine.work(cfg)
+    assert work.weight_bytes > 0 and work.kv_bytes_per_token > 0
+    assert work.head_flops > 0 and work.token_flops(2) > work.token_flops(1)
+    assert work.prefill_flops(0, 2) == work.prefill_flops(0, 1) \
+        + work.prefill_flops(1, 1)
+    params = jax.eval_shape(lambda: engine.program_params(
+        cfg, ref.make_weights(cfg, 3), mcfg))
+    assert jax.tree.leaves(params)
 
 
 @pytest.mark.parametrize("w", SPEC["workloads"], ids=lambda w: w["name"])
@@ -193,11 +206,118 @@ def test_model_options_set_the_program_tree(options):
     cfg = dict(cell.load_json(BENCH / "tests" / "data" / "tiny-qwen3.json"),
                model_options=options)
     ref = cell.load_module(BENCH / "configs" / "qwen3-1.7b.ref.py")
+    assert engine.adapter(cfg).__name__ == "benchkit.models.qwen3"
     mcfg = engine.model_config(cfg)
     assert (mcfg.qk_norm, mcfg.gated_ffn, mcfg.act) == (
         options["qk_norm"], options["gated_ffn"], options["act"])
-    params = engine.program_params(ref.make_weights(cfg, 3), mcfg)
+    params = engine.program_params(cfg, ref.make_weights(cfg, 3), mcfg)
     block = params["segments"][0][0]
     assert ("q_norm" in block["attn"]) == options["qk_norm"]
     assert ("w_gate" in block["ffn"]) == options["gated_ffn"]
-    assert counting.Shape.of(cfg).gated == options["gated_ffn"]
+    assert engine.work(cfg).gated == options["gated_ffn"]
+
+
+def test_pool_pages_per_cell():
+    """The pool is the exact worst case of a full batch at the traffic's
+    longest request, plus the null page, rounded up to a multiple of the
+    chips the pages are striped over."""
+    pages = {}
+    for w in SPEC["workloads"]:
+        cfg = cell.load_json(cell.config_path(SPEC, w["config"]))
+        mix = cell.load_json(BENCH / "traffic" / f"{w['traffic']}.json")
+        ps = cfg["engine"]["page_size"]
+        sched = traffic.build(mix, 5, 10, 1000)
+        max_len = -(-sched.max_tokens // ps) * ps
+        pages[w["name"]] = engine.pool_pages(cfg["engine"], max_len,
+                                             w["chips"])
+        assert pages[w["name"]] % w["chips"] == 0
+    assert pages == {"qwen3-1.7b.chat": 2049, "qwen3-1.7b.sessions": 2289}
+
+
+@pytest.mark.parametrize("chips,pages", [(1, 2049), (2, 2050), (4, 2052)])
+def test_striped_pools_round_up_to_the_chips(chips, pages):
+    """On several chips the one-chip rule's pages (16 slots of 128 pages
+    of 16 tokens, plus the null page) are rounded up to a multiple of the
+    chips, so that each chip holds as many."""
+    opts = {"max_batch": 16, "page_size": 16}
+    assert engine.pool_pages(opts, 2048, chips) == pages
+
+
+@pytest.mark.parametrize("chips,before,after", [
+    (1, "tpu", "tpu"), (4, "tpu", "tpu,cpu"), (4, "tpu,cpu", "tpu,cpu"),
+    (4, "", "")])
+def test_striped_cells_stage_the_pools_on_the_host(monkeypatch, chips,
+                                                   before, after):
+    """A cell on several chips adds the host's platform after the chips'
+    (the striped pools are made in host memory first); a one-chip cell,
+    a platform list that has it, or none at all is left as it is."""
+    from types import SimpleNamespace
+    fake = SimpleNamespace(jax_platforms=before)
+    fake.update = lambda key, value: setattr(fake, key, value)
+    monkeypatch.setattr(jax, "config", fake)
+    engine.stage_on_host(chips)
+    assert fake.jax_platforms == after
+
+
+def test_a_family_is_found_by_its_model_type(monkeypatch):
+    """A configuration of a family the benchmark does not run loads
+    through its own module, found by the file's ``model_type``, with no
+    edit to the harness: here a module beside the tests, on the family
+    package's search path."""
+    monkeypatch.setattr(models, "__path__", [*models.__path__, str(DATA)])
+    cfg = cell.load_json(DATA / "tiny-dense.json")
+    ref = cell.load_module(DATA / cfg["reference"])
+    family = engine.adapter(cfg)
+    assert family.__file__ == str(DATA / "tinydense.py")
+    mcfg = engine.model_config(cfg)
+    assert not mcfg.tie_embeddings and mcfg.qk_norm
+    params = engine.program_params(cfg, ref.make_weights(cfg, 3), mcfg)
+    assert params["head"]["head_w"].shape == (128, 4096)
+    work = engine.work(cfg)
+    assert isinstance(work, family.Work)
+    # the counting reads only the work count's members
+    f, b = counting.decode_step(work, [10])
+    assert f == work.token_flops(10) + work.head_flops
+    assert b == work.weight_bytes + 10 * work.kv_bytes_per_token
+    assert counting.prefill(work, 0, 4, logits=True) == \
+        work.prefill_flops(0, 4) + work.head_flops
+    # a tree the program would not build is refused
+    with pytest.raises(ValueError, match="parameter tree has changed"):
+        engine.program_params(cfg, ref.make_weights(cfg, 3),
+                              dataclasses.replace(mcfg, tie_embeddings=True))
+
+
+QWEN3_TREE = [
+    ("['embed']['embed_table']", (151936, 2048), "bfloat16"),
+    ("['final_norm']['scale']", (2048,), "float32"),
+    ("['segments'][0][0]['attn']['k_norm']", (28, 128), "float32"),
+    ("['segments'][0][0]['attn']['q_norm']", (28, 128), "float32"),
+    ("['segments'][0][0]['attn']['wk']", (28, 2048, 1024), "bfloat16"),
+    ("['segments'][0][0]['attn']['wo']", (28, 2048, 2048), "bfloat16"),
+    ("['segments'][0][0]['attn']['wq']", (28, 2048, 2048), "bfloat16"),
+    ("['segments'][0][0]['attn']['wv']", (28, 2048, 1024), "bfloat16"),
+    ("['segments'][0][0]['ffn']['w_down']", (28, 6144, 2048), "bfloat16"),
+    ("['segments'][0][0]['ffn']['w_gate']", (28, 2048, 6144), "bfloat16"),
+    ("['segments'][0][0]['ffn']['w_up']", (28, 2048, 6144), "bfloat16"),
+    ("['segments'][0][0]['ln1']['scale']", (28, 2048), "float32"),
+    ("['segments'][0][0]['ln2']['scale']", (28, 2048), "float32"),
+]
+
+
+def test_qwen3_program_tree_is_pinned():
+    """qwen3-1.7b's parameter tree, leaf by leaf, as the benchmark handed
+    it to the program before model families became modules of their
+    own; every matrix is the reference's own array."""
+    cfg = cell.load_json(BENCH / "configs" / "qwen3-1.7b.json")
+    ref = cell.load_module(BENCH / "configs" / cfg["reference"])
+    mcfg = engine.model_config(cfg)
+    tree = jax.eval_shape(lambda: engine.program_params(
+        cfg, ref.make_weights(cfg, 3), mcfg))
+    assert [(jax.tree_util.keystr(p), leaf.shape, str(leaf.dtype))
+            for p, leaf in jax.tree_util.tree_leaves_with_path(tree)] \
+        == QWEN3_TREE
+    tiny = cell.load_json(DATA / "tiny-qwen3.json")
+    weights = ref.make_weights(tiny, 3)
+    params = engine.program_params(tiny, weights, engine.model_config(tiny))
+    assert params["segments"][0][0]["attn"]["wq"] is weights["wq"]
+    assert params["embed"]["embed_table"] is weights["embed"]

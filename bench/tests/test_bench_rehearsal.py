@@ -7,13 +7,18 @@ trace); the chip-only entry point refuses the CPU and a directory with
 no program; the control (the reference at float8) fails the check that
 the program passes; and a token altered where the engine produces it,
 or a decode window that returns the KV cache unchanged, turns
-``correct`` false.
+``correct`` false.  A family the benchmark does not run goes through
+the same run by its own module; a four-chip cell runs on four virtual
+CPU devices with its pools striped over them, and turns ``correct``
+false where the exchange between the chips is left out.
 """
 import json
 import math
+import os
 import shutil
 import subprocess
 import sys
+import textwrap
 import time
 from pathlib import Path
 
@@ -31,8 +36,9 @@ CELL = SPEC["workloads"][0]["name"]
 KEYS = {"correct", "attempted", "failed", "metrics", "device", "check"}
 
 
-def rehearse(mix, trace=False, seed=2 ** 31 + 99, seconds=1.0, **kw):
-    """One run of ``mix`` on the tiny configuration; returns the result
+def rehearse(mix, trace=False, seed=2 ** 31 + 99, seconds=1.0,
+             config="tiny-qwen3", **kw):
+    """One run of ``mix`` on a tiny configuration; returns the result
     and the harness's own outcome."""
     seen = {}
     run = harness.Loop.run
@@ -45,7 +51,7 @@ def rehearse(mix, trace=False, seed=2 ** 31 + 99, seconds=1.0, **kw):
         res = cell.run(SPEC, CELL, seed, seconds, trace,
                        t_start=time.perf_counter(), platform="cpu",
                        compile_cache=False,
-                       config_file=DATA / "tiny-qwen3.json",
+                       config_file=DATA / f"{config}.json",
                        mix=cell.load_json(DATA / f"{mix}.json"),
                        log=lambda m: None, **kw)
     finally:
@@ -165,3 +171,156 @@ def test_a_decode_window_that_leaves_the_cache_unchanged_is_caught(
     monkeypatch.setattr(engine_mod, "_jitted_steps", broken)
     res, _ = rehearse("open")
     assert not res["correct"]
+
+
+def test_a_family_the_benchmark_does_not_run_goes_through_a_whole_run(
+        monkeypatch):
+    """``tinydense`` (a module beside the tests, on the family package's
+    search path) is found by its configuration's ``model_type``, and a
+    whole run serves it, counts its work with its own work count and
+    checks it against the reference."""
+    from benchkit import models
+    monkeypatch.setattr(models, "__path__", [*models.__path__, str(DATA)])
+    res, out = rehearse("prefix", trace=True, config="tiny-dense")
+    assert res["correct"], res["check"]
+    assert out.work is not None and out.work.decode_flops > 0
+    assert out.counters["prefill_tokens_cached"] > 0
+
+
+def test_chained_windows_are_warmed_on_striped_pools_only(monkeypatch):
+    """On one chip the engine's own warm-up compiles every decode window
+    as the window dispatches it, so the benchmark's chained warm-up,
+    which only striped pools need, is not run there."""
+    from benchkit import engine, traffic
+    called = []
+    monkeypatch.setattr(engine, "warm_chained_windows", called.append)
+    cfg = cell.load_json(DATA / "tiny-qwen3.json")
+    ref = cell.load_module(DATA / cfg["reference"])
+    mcfg = engine.model_config(cfg)
+    eng = engine.build(cfg, mcfg, engine.program_params(
+        cfg, ref.make_weights(cfg, 5), mcfg), 64)
+    sched = traffic.build(cell.load_json(DATA / "prefix.json"), 5, 1.0,
+                          cfg["vocab_size"])
+    engine.warm(eng, sched, cfg["vocab_size"])
+    assert eng.mesh is None and called == []
+
+
+# a four-chip cell of the tiny configuration and the tiny shared-prefix
+# mix, whose prefixes mostly end inside a page, so that questions about
+# them copy that page on the striped pools in the window
+X4 = {"name": "tiny.prefix-x4", "config": "tiny-qwen3", "traffic": "prefix",
+      "chips": 4, "why": "pools striped 1x4"}
+
+
+def test_four_chip_cell_on_virtual_devices():
+    """On four virtual CPU devices: the engine the harness builds for
+    four chips stripes its pools over all four, rounds its pages up to a
+    multiple of four, replicates the parameters, and serves the same
+    greedy tokens as the one-chip engine (in float32, where no two
+    logits tie to rounding as bf16's do); and a whole run of a four-chip
+    cell with shared prefixes that end inside a page compiles nothing in
+    its window, the page copies included, copies pages, and passes its
+    check, the reference dealt out over the four chips."""
+    code = textwrap.dedent(f"""
+        import json, sys, time
+        sys.path[:0] = [{str(BENCH)!r}, {str(ROOT / "src")!r}]
+        import jax
+        import numpy as np
+        from benchkit import cell, engine, harness
+        devs = jax.devices()
+        assert len(devs) == 4
+        data = cell.BENCH / "tests" / "data"
+        cfg = dict(cell.load_json(data / "tiny-qwen3.json"),
+                   torch_dtype="float32")
+        ref = cell.load_module(data / cfg["reference"])
+        mcfg = engine.model_config(cfg)
+        served = {{}}
+        for n in (1, 4):
+            w = engine.replicated(ref.make_weights(cfg, 5), devs[:n])
+            eng = engine.build(cfg, mcfg, engine.program_params(cfg, w, mcfg),
+                               64, devs[:n])
+            pools = jax.tree.leaves(eng.pools)
+            assert eng.alloc.n_pages % n == 0
+            assert all(len(p.sharding.device_set) == n for p in pools)
+            if n == 4:
+                assert eng.alloc.n_pages == 36       # 4 x 8 pages + 1, to 36
+                assert all(p.addressable_shards[0].data.shape[-3]
+                           == p.shape[-3] // 4 for p in pools)
+                assert all(len(a.sharding.device_set) == 4
+                           and a.sharding.is_fully_replicated
+                           for a in jax.tree.leaves(eng.params))
+            rng = np.random.default_rng(3)
+            reqs = [eng.submit(rng.integers(0, cfg["vocab_size"], int(n_),
+                                            dtype=np.int32), 12)
+                    for n_ in (5, 17, 30, 44, 9, 50)]
+            eng.run()
+            served[n] = [list(r.tokens) for r in reqs]
+        assert served[1] == served[4], served
+        compiled = []
+        count = harness.CompileCounter._on
+        def named(self, event, duration, **kw):
+            if self.armed and event == self.EVENT:
+                compiled.append(kw.get("fun_name"))
+            count(self, event, duration, **kw)
+        harness.CompileCounter._on = named
+        copies = []
+        loop = harness.Loop.run
+        def run(self, *a, **kw):
+            out = loop(self, *a, **kw)
+            copies.append(self.eng.cache.stats.cow_copies)
+            return out
+        harness.Loop.run = run
+        spec = cell.load_json(cell.ROOT / "BENCHMARK.json")
+        spec["workloads"].append({X4!r})
+        res = cell.run(spec, {X4["name"]!r}, 2 ** 31 + 41, 2.0,
+                       False, t_start=time.perf_counter(), platform="cpu",
+                       compile_cache=False,
+                       config_file=data / "tiny-qwen3.json",
+                       mix=cell.load_json(data / "prefix.json"),
+                       log=lambda m: None)
+        assert res["correct"], res["check"]
+        assert res["device"]["count"] == 4 and res["attempted"] > 0
+        assert copies[0] > 0, copies
+        assert compiled == [], compiled
+    """)
+    on_four_devices(code)
+
+
+def test_four_chip_cell_without_the_exchange_between_chips_is_caught():
+    """A four-chip cell's striped decode merges each chip's partial
+    attention over the pages it owns across the chips; with that merge
+    left out (each chip keeps its own partial), a whole run of the cell
+    on four virtual CPU devices turns ``correct`` false."""
+    code = textwrap.dedent(f"""
+        import sys, time
+        sys.path[:0] = [{str(BENCH)!r}, {str(ROOT / "src")!r}]
+        import jax
+        from benchkit import cell
+        # the partials' merge: a max and two sums over the stripe axis
+        jax.lax.pmax = lambda x, axes: x
+        jax.lax.psum = lambda x, axes: x
+        data = cell.BENCH / "tests" / "data"
+        spec = cell.load_json(cell.ROOT / "BENCHMARK.json")
+        spec["workloads"].append({X4!r})
+        res = cell.run(spec, {X4["name"]!r}, 2 ** 31 + 43, 2.0, False,
+                       t_start=time.perf_counter(), platform="cpu",
+                       compile_cache=False,
+                       config_file=data / "tiny-qwen3.json",
+                       mix=cell.load_json(data / "prefix.json"),
+                       log=lambda m: None)
+        gap = res["check"]["logit_gap"]
+        assert not res["correct"] and gap["value"] > gap["limit"], gap
+    """)
+    on_four_devices(code)
+
+
+def on_four_devices(code: str) -> None:
+    """Runs ``code`` in a process of its own on four virtual CPU
+    devices; it has to end without an error."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                        + " --xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", code + "\nprint('OK')\n"],
+                       capture_output=True, text=True, env=env, timeout=600)
+    assert p.returncode == 0, p.stdout[-2000:] + p.stderr[-4000:]
+    assert p.stdout.strip().endswith("OK")

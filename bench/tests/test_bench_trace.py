@@ -45,6 +45,30 @@ def test_reduce_by_hand():
                               ["bench.step", pytest.approx(100e-9)]]
 
 
+def test_collective_time_per_program_averaged_over_devices():
+    """Self time of the operations that move data between chips, per
+    program, averaged over the devices; the keys the reduction already
+    returned are unchanged."""
+    assert trace.reduce(HAND)["collective_s"] == pytest.approx(
+        {"chunk_prefill": 20e-9})
+    dev = {"modules": [["jit_serve_scan(3)", 100, 500]],
+           "ops": [["while.1", 100, 500], ["fusion.1", 100, 300],
+                   ["all-reduce-start.2", 300, 310],
+                   ["all-reduce-done.2", 310, 350],
+                   ["collective-permute.4", 350, 400],
+                   ["fusion.3", 400, 500]]}
+    other = {"modules": dev["modules"],
+             "ops": [["fusion.1", 100, 480], ["all-gather.5", 480, 500]]}
+    rec = {"devices": {"/device:TPU:0": dev, "/device:TPU:1": other},
+           "host": [["bench.traced", 0, 1000]]}
+    r = trace.reduce(rec)
+    assert r["collective_s"] == pytest.approx({"serve_scan": (
+        10e-9 + 40e-9 + 50e-9 + 20e-9) / 2})
+    assert r["program_s"] == pytest.approx({"serve_scan": 400e-9})
+    assert set(r) == {"window_s", "busy_s", "idle_share", "program_s",
+                      "collective_s", "device_ops", "idle_gaps"}
+
+
 def test_program_names():
     assert trace.program_name("jit_serve_scan(17)") == "serve_scan"
     assert trace.program_name("copy_page") == "copy_page"
