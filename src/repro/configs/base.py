@@ -32,6 +32,49 @@ class MoEConfig:
     # softmax-top-k with an optional load-balance aux loss.
     aux_loss_coef: float = 0.001
     score_func: str = "softmax"    # softmax | sigmoid (deepseek-v3 uses sigmoid)
+    # DeepSeek-V3's "noaux_tc" selection: a correction bias added to the
+    # scores for the choice only, ``n_group`` groups of experts of which
+    # the ``topk_group`` with the best sum of their two best choice
+    # scores are kept; the chosen scores are normalised and scaled
+    topk_method: str = "greedy"    # greedy | noaux_tc
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # the routed experts this device holds: ``n_held`` experts from id
+    # ``first_held`` (None: all of them).  The router still scores all
+    # ``n_experts``; a token's pairs on other experts are left out
+    n_held: Optional[int] = None
+    first_held: int = 0
+
+    def __post_init__(self):
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"unknown topk_method {self.topk_method!r}")
+        if self.n_experts % self.n_group or self.topk_group > self.n_group:
+            raise ValueError("n_group must divide n_experts and hold "
+                             "topk_group")
+        if not 0 <= self.first_held < self.first_held + self.held \
+                <= self.n_experts:
+            raise ValueError("the held experts must lie among n_experts")
+
+    @property
+    def held(self) -> int:
+        """How many routed experts this device holds."""
+        return self.n_experts if self.n_held is None else self.n_held
+
+
+@dataclass(frozen=True)
+class RopeScaling:
+    """YaRN (arXiv:2309.00071) as DeepSeek-V3's ``rope_scaling`` gives
+    it: ``factor`` times the ``original_max_position_embeddings``, the
+    frequencies blended between the ``beta_fast`` and ``beta_slow``
+    correction dimensions, and the magnitude scales ``mscale`` (of cos
+    and sin) and ``mscale_all_dim`` (of the attention scale)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -65,6 +108,7 @@ class ModelConfig:
     attn_softcap: Optional[float] = None     # gemma2: 50.0
     logit_softcap: Optional[float] = None    # gemma2: 30.0
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[RopeScaling] = None   # YaRN (deepseek-v3)
     sliding_window: int = 4096     # for LOCAL layers
     mrope_sections: Optional[Tuple[int, int, int]] = None  # qwen2-vl M-RoPE
     attn_logit_scale: Optional[float] = None  # override 1/sqrt(head_dim)
@@ -109,6 +153,9 @@ class ModelConfig:
                 raise ValueError(f"unknown layer kind {k!r}")
         if self.n_heads % self.n_kv_heads:
             raise ValueError("n_heads must be a multiple of n_kv_heads")
+        if self.rope_scaling is not None and set(self.layer_pattern) != {MLA}:
+            raise ValueError("YaRN rope scaling is built into latent "
+                             "attention (mla) layers only")
         # n_layers need not be divisible by the pattern period: the model
         # scans over full cycles and unrolls the remainder (recurrentgemma:
         # 26 layers over a (rglru, rglru, local) period-3 pattern).

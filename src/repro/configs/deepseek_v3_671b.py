@@ -3,13 +3,17 @@
 MoE: 61L, d_model=7168, 128 attention heads with MLA (q_lora 1536, kv_lora
 512, nope 128 + rope 64 q/k dims, v 128), vocab=129280.  First 3 layers are
 dense FFN (d_ff=18432); the remaining 58 use 1 shared + 256 routed experts
-(d_ff_expert=2048), sigmoid-score top-8 routing, plus 1 multi-token-
-prediction module.  671B total / ~37B active parameters.
+(d_ff_expert=2048), plus 1 multi-token-prediction module.  671B total /
+~37B active parameters.  Routing is the published ``noaux_tc``: sigmoid
+scores, a correction bias for the choice, 8 groups of 32 experts of
+which the best 4 are kept, top-8 within them, weights normalised and
+scaled by 2.5.  Rotary embedding is YaRN (factor 40 over 4,096 original
+positions, beta_fast 32, beta_slow 1, mscale = mscale_all_dim = 1).
 
 Memory policy (16 GB/chip on a 256-chip pod): bf16 parameters and int8
 block-quantized Adam moments (see optim/adam.py).
 """
-from repro.configs.base import MLAConfig, ModelConfig, MoEConfig
+from repro.configs.base import MLAConfig, ModelConfig, MoEConfig, RopeScaling
 
 CONFIG = ModelConfig(
     name="deepseek-v3-671b",
@@ -26,11 +30,17 @@ CONFIG = ModelConfig(
     mla=MLAConfig(q_lora_rank=1536, kv_lora_rank=512,
                   qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(n_experts=256, top_k=8, d_ff_expert=2048, n_shared=1,
-                  capacity_factor=1.25, score_func="sigmoid"),
+                  capacity_factor=1.25, score_func="sigmoid",
+                  topk_method="noaux_tc", n_group=8, topk_group=4,
+                  routed_scaling_factor=2.5),
     mtp_depth=1,
     act="silu",
     gated_ffn=True,
     rope_theta=10_000.0,
+    rope_scaling=RopeScaling(factor=40.0,
+                             original_max_position_embeddings=4096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                             mscale_all_dim=1.0),
     param_dtype="bfloat16",
     opt_state_dtype="int8",
 )
@@ -43,6 +53,12 @@ def tiny() -> ModelConfig:
         mla=MLAConfig(q_lora_rank=32, kv_lora_rank=16,
                       qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16),
         moe=MoEConfig(n_experts=8, top_k=2, d_ff_expert=32, n_shared=1,
-                      capacity_factor=1.25, score_func="sigmoid"),
+                      capacity_factor=1.25, score_func="sigmoid",
+                      topk_method="noaux_tc", n_group=4, topk_group=2,
+                      routed_scaling_factor=2.5),
+        rope_scaling=RopeScaling(factor=4.0,
+                                 original_max_position_embeddings=16,
+                                 beta_fast=32.0, beta_slow=1.0, mscale=1.0,
+                                 mscale_all_dim=1.0),
         mtp_depth=1, param_dtype="float32", opt_state_dtype="float32",
         attn_block_q=16, attn_block_kv=32)

@@ -367,6 +367,27 @@ def _at(layer, *idx):
     return idx if layer is None else (layer,) + idx
 
 
+def gather_pages(leaf, block_tables, layer=None):
+    """The rows of the pages ``block_tables`` (..., nmax) names, in
+    order: (..., nmax * page_size, F) from a pool leaf (P, ps, F), or
+    from row ``layer`` of its stack (``_at``), in one gather.  The
+    paged decode of every pool kind (GQA keys and values, latent rows)
+    reads its sequences through this."""
+    rows = leaf[_at(layer, block_tables)]
+    return rows.reshape(*block_tables.shape[:-1], -1, leaf.shape[-1])
+
+
+def causal_valid(T: int, pos):
+    """(…, T) mask of the first T key positions a query at ``pos`` (…,)
+    attends to: key t is valid where ``t <= pos``."""
+    return jnp.arange(T) <= pos[..., None]
+
+
+def paged_row_width(cfg) -> int:
+    """Elements one token occupies in a layer's pool: its k and v."""
+    return 2 * cfg.n_kv_heads * cfg.head_dim
+
+
 def paged_cache_init(cfg, n_pages: int, page_size: int, dtype):
     shape = (n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     return PagedAttnCache(k=jnp.zeros(shape, dtype),
@@ -527,14 +548,14 @@ def attend_decode_paged(q, pool: PagedAttnCache, block_tables, pos, *,
     T = nmax * ps
     G = H // Kv
     with jax.named_scope("kv_gather"):
-        k = pool.k[_at(layer, block_tables)].reshape(B, T, Kv, hd)
-        v = pool.v[_at(layer, block_tables)].reshape(B, T, Kv, hd)
+        k = gather_pages(pool.k, block_tables, layer).reshape(B, T, Kv, hd)
+        v = gather_pages(pool.v, block_tables, layer).reshape(B, T, Kv, hd)
     with jax.named_scope("attend"):
         qg = q.reshape(B, Kv, G, hd)
         s = jnp.einsum("bkgd,btkd->bkgt", qg, k,
                        preferred_element_type=jnp.float32) * scale
         s = nn.softcap(s, softcap)
-        ok = jnp.arange(T)[None, :] <= pos[:, None]
+        ok = causal_valid(T, pos)
         s = jnp.where(ok[:, None, None, :], s, NEG_INF)
         w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
         o = jnp.einsum("bkgt,btkd->bkgd", w.astype(v.dtype), v,
@@ -579,14 +600,14 @@ def attend_prefill_paged(q, pool: PagedAttnCache, block_row, start, *,
     Kv = n_kv
     G = H // Kv
     with jax.named_scope("kv_gather"):
-        k = pool.k[_at(layer, block_row)].reshape(B, T, Kv, hd)
-        v = pool.v[_at(layer, block_row)].reshape(B, T, Kv, hd)
+        k = gather_pages(pool.k, block_row, layer).reshape(B, T, Kv, hd)
+        v = gather_pages(pool.v, block_row, layer).reshape(B, T, Kv, hd)
     with jax.named_scope("attend"):
         qg = q.reshape(B, W, Kv, G, hd)
         s = jnp.einsum("bwkgd,btkd->bkgwt", qg, k,
                        preferred_element_type=jnp.float32) * scale
         s = nn.softcap(s, softcap)
-        ok = jnp.arange(T)[None, :] <= (start + jnp.arange(W))[:, None]
+        ok = causal_valid(T, start + jnp.arange(W))
         s = jnp.where(ok[None, None, None], s, NEG_INF)
         w = jax.nn.softmax(s.astype(jnp.float32), axis=-1)
         o = jnp.einsum("bkgwt,btkd->bwkgd", w.astype(v.dtype), v,

@@ -65,7 +65,8 @@ def _post(p, cfg, name, y):
 
 
 def _ffn_part(p, cfg, x):
-    """FFN or MoE (+ shared experts). Returns (out, aux)."""
+    """FFN or MoE (+ shared experts), the training path (capacity
+    routing). Returns (out, aux)."""
     aux = jnp.zeros((), jnp.float32)
     if "moe" in p:
         out, aux = moe_mod.apply(p["moe"], cfg, x)
@@ -75,6 +76,25 @@ def _ffn_part(p, cfg, x):
     else:
         out = nn.ffn_apply(p["ffn"], cfg, x)
     return out, aux
+
+
+def _ffn_infer(p, cfg, h):
+    """FFN of the normed ``h``, or the dropless expert layer over the
+    held experts (``moe.apply_dropless``, scopes ``router`` and
+    ``experts``) plus the shared experts (scope ``shared``): the
+    inference path, where a token's output does not depend on its
+    batch.  Returns (out, pairs per token on held experts, zeros for a
+    dense FFN)."""
+    if "moe" not in p:
+        return nn.ffn_apply(p["ffn"], cfg, h), \
+            jnp.zeros(h.shape[:-1], jnp.int32)
+    out, held = moe_mod.apply_dropless(p["moe"], cfg, h)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            shared_cfg = cfg.replace(
+                d_ff=cfg.moe.d_ff_expert * cfg.moe.n_shared)
+            out = out + nn.ffn_apply(p["shared"], shared_cfg, h)
+    return out, held
 
 
 def apply(p, cfg, kind: str, x, *, angles, mode: str, impl=None):
@@ -126,7 +146,10 @@ def apply(p, cfg, kind: str, x, *, angles, mode: str, impl=None):
         raise ValueError(kind)
 
     h2 = _seq_sp(nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps))
-    out2, aux = _ffn_part(p, cfg, h2)
+    if mode == "train":
+        out2, aux = _ffn_part(p, cfg, h2)
+    else:
+        out2, _ = _ffn_infer(p, cfg, h2)
     x = x + _post(p, cfg, "ln2_post", out2)
     return x, cache_out, aux
 
@@ -156,74 +179,81 @@ def apply_decode(p, cfg, kind: str, x, cache, pos, *, angles):
         raise ValueError(kind)
 
     h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
-    out2, _ = _ffn_part(p, cfg, h2)
+    out2, _ = _ffn_infer(p, cfg, h2)
     x = x + _post(p, cfg, "ln2_post", out2)
     return x, cache
 
 
-def apply_decode_paged(p, cfg, kind: str, x, pool, block_tables, pos, *,
-                       angles, layer=None):
-    """Single-token decode against a paged KV pool. Returns (x, pool).
-    ``layer`` names the row when ``pool`` is a scanned segment's stack.
+# the mixers whose state pages: global attention (k/v) and latent
+# attention (one latent row per token); a sliding-window ring cache and
+# the recurrent states are constant-size per sequence, nothing to page
+PAGED = {ATTN: attention, MLA: mla_mod}
 
-    Only global attention pages cleanly (a sliding-window ring cache and
-    the recurrent states are constant-size per sequence — nothing to
-    page); the serving engine asserts an attention-only config.
-    """
-    if kind != ATTN:
+
+def _paged_mixer(kind: str):
+    if kind not in PAGED:
         raise NotImplementedError(
-            f"paged decode supports global-attention layers only, got {kind!r}")
-    with jax.named_scope("qkv"):
-        h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
-    out, pool = attention.apply_decode_paged(p["attn"], cfg, h, pool,
-                                             block_tables, pos,
-                                             angles=angles, layer=layer)
-    with jax.named_scope("out_proj"):
-        x = x + _post(p, cfg, "ln1_post", out)
+            f"paged KV pools exist for global and latent attention "
+            f"layers only, got {kind!r}")
+    return PAGED[kind], "attn" if kind == ATTN else "mla"
+
+
+def _paged_mlp(p, cfg, x):
     with jax.named_scope("mlp"):
         h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
-        out2, _ = _ffn_part(p, cfg, h2)
-        x = x + _post(p, cfg, "ln2_post", out2)
-    return x, pool
+        out2, held = _ffn_infer(p, cfg, h2)
+        return x + _post(p, cfg, "ln2_post", out2), held
+
+
+def apply_decode_paged(p, cfg, kind: str, x, pool, block_tables, pos, *,
+                       angles, layer=None):
+    """Single-token decode against a paged pool (GQA k/v or latent
+    rows).  ``layer`` names the row when ``pool`` is a scanned segment's
+    stack.  Returns (x, pool, pairs per token on held experts)."""
+    mod, name = _paged_mixer(kind)
+    with jax.named_scope("qkv"):
+        h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
+    out, pool = mod.apply_decode_paged(p[name], cfg, h, pool, block_tables,
+                                       pos, angles=angles, layer=layer)
+    with jax.named_scope("out_proj"):
+        x = x + _post(p, cfg, "ln1_post", out)
+    x, held = _paged_mlp(p, cfg, x)
+    return x, pool, held
 
 
 def apply_prefill_paged(p, cfg, kind: str, x, pool, block_row, start,
                         n_valid, *, angles, layer=None):
-    """Suffix prefill against a paged KV pool (prefix-cache hit): x
-    (1,W,D) tokens at positions start..start+W-1 attend the cached
-    prefix through the block row; ``layer`` as in
-    :func:`apply_decode_paged`.  Returns (x, pool)."""
-    if kind != ATTN:
-        raise NotImplementedError(
-            f"paged prefill supports global-attention layers only, "
-            f"got {kind!r}")
+    """Prefill slice against a paged pool (chunk, prefix-cache suffix,
+    verify): x (1,W,D) tokens at positions start..start+W-1 attend the
+    sequence's earlier state through the block row; ``layer`` as in
+    :func:`apply_decode_paged`.  Returns (x, pool, held pairs)."""
+    mod, name = _paged_mixer(kind)
     with jax.named_scope("qkv"):
         h = nn.rmsnorm(x, p["ln1"]["scale"], cfg.norm_eps)
-    out, pool = attention.apply_prefill_paged(p["attn"], cfg, h, pool,
-                                              block_row, start, n_valid,
-                                              angles=angles, layer=layer)
+    out, pool = mod.apply_prefill_paged(p[name], cfg, h, pool, block_row,
+                                        start, n_valid, angles=angles,
+                                        layer=layer)
     with jax.named_scope("out_proj"):
         x = x + _post(p, cfg, "ln1_post", out)
-    with jax.named_scope("mlp"):
-        h2 = nn.rmsnorm(x, p["ln2"]["scale"], cfg.norm_eps)
-        out2, _ = _ffn_part(p, cfg, h2)
-        x = x + _post(p, cfg, "ln2_post", out2)
-    return x, pool
+    x, held = _paged_mlp(p, cfg, x)
+    return x, pool, held
+
+
+def paged_row_width(cfg, kind: str) -> int:
+    """Elements one token occupies in a ``kind`` layer's pool."""
+    return _paged_mixer(kind)[0].paged_row_width(cfg)
 
 
 def paged_cache_init(cfg, kind: str, n_pages: int, page_size: int, dtype):
-    if kind != ATTN:
-        raise NotImplementedError(
-            f"paged KV pools exist for global attention only, got {kind!r}")
-    return attention.paged_cache_init(cfg, n_pages, page_size, dtype)
+    return _paged_mixer(kind)[0].paged_cache_init(cfg, n_pages, page_size,
+                                                  dtype)
 
 
 def paged_cache_from_prefill(cfg, kind: str, pool, raw, block_row):
-    """Scatter one sequence's prefill kv into its pages."""
-    if kind != ATTN:
-        raise NotImplementedError(kind)
-    k, v = raw
-    return attention.paged_cache_from_prefill(pool, k, v, block_row)
+    """Scatter one sequence's prefill state (k/v, or c_kv and k_rope)
+    into its pages."""
+    return _paged_mixer(kind)[0].paged_cache_from_prefill(pool, *raw,
+                                                          block_row)
 
 
 def cache_init(cfg, kind: str, batch: int, max_len: int, dtype):

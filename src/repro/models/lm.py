@@ -122,7 +122,7 @@ def _angles(cfg, positions):
     if not cfg.rope:
         return None
     return nn.rope_angles(positions, _rope_dim(cfg), cfg.rope_theta,
-                          cfg.mrope_sections)
+                          cfg.mrope_sections, cfg.rope_scaling)
 
 
 def default_positions(cfg, batch: int, seq: int, offset: int = 0):
@@ -419,19 +419,22 @@ def init_caches(cfg, batch: int, max_len: int):
 # paged decode — pools + block tables instead of per-sequence slabs
 # ---------------------------------------------------------------------------
 def paged_decodable(cfg) -> bool:
-    """Paged serving needs causal, embedded-token, global-attention-only
-    configs (windows and recurrent states are constant-size — nothing to
-    page) and no M-RoPE (per-sequence positions are scalar per step)."""
+    """Paged serving needs causal, embedded-token configs whose every
+    layer keeps a per-token cache: global attention (k/v) or latent
+    attention (one latent row) — windows and recurrent states are
+    constant-size, nothing to page — and no M-RoPE (per-sequence
+    positions are scalar per step)."""
     return (cfg.supports_decode and cfg.embed_inputs
             and cfg.mrope_sections is None
-            and all(k == "attn" for k in cfg.layer_kinds))
+            and all(k in blocks.PAGED for k in cfg.layer_kinds))
 
 
 def init_paged_caches(cfg, n_pages: int, page_size: int):
-    """Per-layer paged KV pools, mirroring the init_caches pytree: one
-    PagedAttnCache per layer, stacked (n_cycles, ...) for scanned
-    segments.  All layers share one block table — page ids are logical
-    across the whole stack, exactly the vLLM layout."""
+    """Per-layer paged pools, mirroring the init_caches pytree: one pool
+    of the layer kind's own (``PagedAttnCache``, ``PagedLatentCache``)
+    per layer, stacked (n_cycles, ...) for scanned segments.  All layers
+    share one block table — page ids are logical across the whole
+    stack, exactly the vLLM layout."""
     assert paged_decodable(cfg), f"{cfg.name} is not paged-decodable"
     dtype = nn.dt(cfg.activation_dtype)
     segs = make_segments(cfg)
@@ -447,10 +450,19 @@ def init_paged_caches(cfg, n_pages: int, page_size: int):
     return out
 
 
+def paged_bytes_per_token(cfg) -> int:
+    """Bytes one token occupies in the paged pools, over every layer
+    (each pool module's row width, ``blocks.paged_row_width``)."""
+    item = jnp.dtype(nn.dt(cfg.activation_dtype)).itemsize
+    return sum(blocks.paged_row_width(cfg, k) for k in cfg.layer_kinds) \
+        * item
+
+
 def paged_from_prefill(cfg, pools, raw_caches, block_row):
-    """Scatter ONE sequence's prefill kv (from forward(mode="prefill"),
-    batch 1) into the pools at the pages named by ``block_row``: one
-    scatter per stacked pool covers all its layers, in place."""
+    """Scatter ONE sequence's prefill state (from forward(mode=
+    "prefill"), batch 1) into the pools at the pages named by
+    ``block_row``: one scatter per stacked pool covers all its layers,
+    in place."""
     return [tuple(blocks.paged_cache_from_prefill(cfg, kind, seg_pool[j],
                                                   seg_raw[j], block_row)
                   for j, kind in enumerate(seg.kinds))
@@ -461,7 +473,8 @@ def paged_from_prefill(cfg, pools, raw_caches, block_row):
 def _paged_layers(params, cfg, x, pools, apply):
     """Run x through every layer against the paged pools, in place.
 
-    ``apply(p, kind, x, pool, layer) -> (x, pool)`` runs one layer.  A
+    ``apply(p, kind, x, pool, layer) -> (x, pool, held)`` runs one
+    layer (``held``: each token's pairs on held experts).  A
     scanned segment's layer scan carries the segment's stacked pools
     with x, and its ``xs`` are the weights and the layer index: each
     layer scatters into and gathers from the stack at ``(layer, ...)``
@@ -469,30 +482,40 @@ def _paged_layers(params, cfg, x, pools, apply):
     Passing the stack as ``xs``/``ys`` instead makes XLA slice every
     layer's slab out, write it into a fresh stack and copy that back,
     three whole-pool moves per step.  Unscanned segments hold one pool
-    per layer (``layer`` None).  Returns (x, new pools).
+    per layer (``layer`` None).  Returns (x, new pools, held pairs per
+    token summed over the layers; None for a model without experts).
     """
+    count = cfg.moe is not None
+    held = jnp.zeros(x.shape[:-1], jnp.int32) if count else None
     new_pools = []
     for seg, seg_p, seg_pool in zip(make_segments(cfg), params["segments"],
                                     pools):
-        def cycle(cyc_p, cyc_pool, x, layer):
+        def cycle(cyc_p, cyc_pool, x, held, layer):
             new_c = []
             for j, kind in enumerate(seg.kinds):
-                x, c = apply(cyc_p[j], kind, x, cyc_pool[j], layer)
+                x, c, h = apply(cyc_p[j], kind, x, cyc_pool[j], layer)
                 new_c.append(c)
-            return x, tuple(new_c)
+                if count:
+                    held = held + h
+            return x, tuple(new_c), held
 
         if seg.scanned:
             def scan_body(carry, inp):
-                x, cyc_pool = carry
+                x, cyc_pool, held = carry
                 cyc_p, layer = inp
-                return cycle(cyc_p, cyc_pool, x, layer), None
-            (x, new_seg), _ = jax.lax.scan(
-                scan_body, (x, seg_pool),
+                return cycle(cyc_p, cyc_pool, x, held, layer), None
+            (x, new_seg, held), _ = jax.lax.scan(
+                scan_body, (x, seg_pool, held),
                 (seg_p, jnp.arange(seg.n_cycles, dtype=jnp.int32)))
         else:
-            x, new_seg = cycle(seg_p, seg_pool, x, None)
+            x, new_seg, held = cycle(seg_p, seg_pool, x, held, None)
         new_pools.append(new_seg)
-    return x, new_pools
+    return x, new_pools, held
+
+
+def moe_layers(cfg) -> int:
+    """How many layers route experts."""
+    return 0 if cfg.moe is None else cfg.n_layers - cfg.first_k_dense
 
 
 def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
@@ -500,7 +523,9 @@ def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
 
     tokens (B,1) int32; block_tables (B,nmax) int32 physical page ids;
     pos (B,) int32 per-sequence positions (inactive slots: 0, with a
-    null-page block row).  Returns (logits (B,1,V), new pools).
+    null-page block row).  Returns (logits (B,1,V), new pools, held
+    pairs per slot (B,) summed over the layers, or None without
+    experts).
     """
     with jax.named_scope("embed"):
         x = embed_tokens(params, cfg, tokens)
@@ -511,10 +536,11 @@ def decode_step_paged(params, cfg, tokens, pools, block_tables, pos):
         return blocks.apply_decode_paged(p, cfg, kind, x, pool, block_tables,
                                          pos, angles=angles, layer=layer)
 
-    x, new_pools = _paged_layers(params, cfg, x, pools, layer_decode)
+    x, new_pools, held = _paged_layers(params, cfg, x, pools, layer_decode)
     with jax.named_scope("head"):
         h = nn.rmsnorm(x, params["final_norm"]["scale"], cfg.norm_eps)
-        return head_logits(params, cfg, h), new_pools
+        return head_logits(params, cfg, h), new_pools, \
+            None if held is None else held[:, 0]
 
 
 def decode_window_paged(params, cfg, tokens, pools, block_tables, pos,
@@ -530,20 +556,31 @@ def decode_window_paged(params, cfg, tokens, pools, block_tables, pos,
     tokens (B,1) int32 last emitted token per slot; pos (B,) int32 write
     positions; active (B,) int32 1 for occupied slots (inactive slots
     hold token/pos fixed so their null-page writes stay at slot 0).
-    Returns (emitted (B,K) int32, last tokens (B,1), pos (B,), pools).
+    Returns (emitted (B,K) int32, last tokens (B,1), pos (B,), pools,
+    counts): ``counts`` is None for a model without experts, else the
+    window's int32 ``[moe_tokens, moe_pairs_held]`` over its occupied
+    slots: tokens through expert layers (one per token and layer) and
+    their (token, expert) pairs on held experts.
     """
+    count = cfg.moe is not None
+
     def body(carry, _):
-        tok, p, pl = carry
-        logits, pl = decode_step_paged(params, cfg, tok, pl, block_tables, p)
+        tok, p, pl, n = carry
+        logits, pl, held = decode_step_paged(params, cfg, tok, pl,
+                                             block_tables, p)
         with jax.named_scope("head"):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B,1)
             nxt = jnp.where(active[:, None] > 0, nxt, tok)
+            if count:
+                n = n + jnp.stack([active.sum() * moe_layers(cfg),
+                                   (active * held).sum()])
             p = p + active
-        return (nxt, p, pl), nxt[:, 0]
+        return (nxt, p, pl, n), nxt[:, 0]
 
-    (tok, pos, pools), toks = jax.lax.scan(body, (tokens, pos, pools),
-                                           None, length=k)
-    return jnp.moveaxis(toks, 0, 1), tok, pos, pools
+    n0 = jnp.zeros((2,), jnp.int32) if count else None
+    (tok, pos, pools, n), toks = jax.lax.scan(
+        body, (tokens, pos, pools, n0), None, length=k)
+    return jnp.moveaxis(toks, 0, 1), tok, pos, pools, n
 
 
 def _suffix_forward_paged(params, cfg, tokens, pools, block_row, start,
@@ -565,7 +602,8 @@ def _suffix_forward_paged(params, cfg, tokens, pools, block_row, start,
                                           start, n_valid, angles=angles,
                                           layer=layer)
 
-    return _paged_layers(params, cfg, x, pools, layer_prefill)
+    x, new_pools, _ = _paged_layers(params, cfg, x, pools, layer_prefill)
+    return x, new_pools
 
 
 def prefill_suffix_paged(params, cfg, tokens, pools, block_row, start,

@@ -1,6 +1,7 @@
 """Shared model primitives: norms, rotary embeddings, activations, FFN."""
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -77,20 +78,58 @@ def activation(name: str):
 # ---------------------------------------------------------------------------
 # rotary position embeddings (standard + qwen2-vl M-RoPE)
 # ---------------------------------------------------------------------------
-def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
+def rope_freqs(head_dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies (head_dim/2,): ``theta^(-2i/head_dim)``, or
+    with ``scaling`` (a ``configs.base.RopeScaling``) YaRN's blend of
+    them with the interpolated ``freq / factor``, as DeepSeek-V3's
+    ``DeepseekV3YarnRotaryEmbedding`` computes it:
+
+        d(r)  = head_dim * ln(L / (2 pi r)) / (2 ln theta)   (L the
+                original_max_position_embeddings)
+        low   = max(floor(d(beta_fast)), 0)
+        high  = min(ceil(d(beta_slow)), head_dim - 1)
+        ramp_i = clip((i - low) / (high - low), 0, 1)
+        inv_i = freq_i / factor * ramp_i + freq_i * (1 - ramp_i)
+
+    so the fast-rotating dimensions (below ``low``) keep their frequency
+    and the slow ones (above ``high``) are interpolated by ``factor``."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    inv = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if scaling is None:
+        return inv
+    low, high = yarn_correction_range(scaling, head_dim, theta)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return inv / scaling.factor * ramp + inv * (1.0 - ramp)
+
+
+def yarn_correction_range(scaling, head_dim: int, theta: float):
+    """(low, high) dimensions of YaRN's ramp (see :func:`rope_freqs`)."""
+    def dim(rotations):
+        return head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+    return (max(math.floor(dim(scaling.beta_fast)), 0),
+            min(math.ceil(dim(scaling.beta_slow)), head_dim - 1))
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude ``0.1 * mscale * ln(factor) + 1`` (1 when
+    ``factor <= 1``), DeepSeek-V3's ``yarn_get_mscale``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def rope_angles(positions, head_dim: int, theta: float,
-                sections: Optional[Tuple[int, int, int]] = None):
+                sections: Optional[Tuple[int, int, int]] = None,
+                scaling=None):
     """Angles (…, S, head_dim/2).
 
     positions: (B, S) int32 for standard RoPE, or (3, B, S) for M-RoPE where
     the three planes are (temporal, height, width) ids and ``sections``
     gives how many of the head_dim/2 frequencies each plane owns.
+    ``scaling``: YaRN's frequencies (:func:`rope_freqs`).
     """
-    inv = rope_freqs(head_dim, theta)                    # (half,)
+    inv = rope_freqs(head_dim, theta, scaling)           # (half,)
     if sections is None:
         ang = positions[..., None].astype(jnp.float32) * inv  # (B,S,half)
         return ang

@@ -32,17 +32,22 @@ from repro.parallel.sharding import compat_shard_map as _shard_map
 
 
 def init(key, cfg, dtype):
+    """The router over all ``n_experts`` (with ``noaux_tc`` its
+    correction bias) and the weights of the experts this device holds
+    (``MoEConfig.held``)."""
     m = cfg.moe
     d, fe = cfg.d_model, m.d_ff_expert
     ks = jax.random.split(key, 5)
     p = {
         "router_w": nn.dense_init(ks[0], d, m.n_experts, jnp.float32),
-        "e_up": _expert_init(ks[1], m.n_experts, d, fe, dtype),
-        "e_down": _expert_init(ks[2], m.n_experts, fe, d, dtype,
+        "e_up": _expert_init(ks[1], m.held, d, fe, dtype),
+        "e_down": _expert_init(ks[2], m.held, fe, d, dtype,
                                scale=1.0 / max(1, cfg.n_layers) ** 0.5),
     }
     if cfg.gated_ffn:
-        p["e_gate"] = _expert_init(ks[3], m.n_experts, d, fe, dtype)
+        p["e_gate"] = _expert_init(ks[3], m.held, d, fe, dtype)
+    if m.topk_method == "noaux_tc":
+        p["router_bias"] = jnp.zeros((m.n_experts,), jnp.float32)
     return p
 
 
@@ -55,8 +60,17 @@ def _expert_init(key, e, d_in, d_out, dtype, scale: float = 1.0):
 # ---------------------------------------------------------------------------
 # routing + dispatch (runs per-shard; pure local math)
 # ---------------------------------------------------------------------------
-def route(cfg, router_w, tokens):
-    """tokens (T, D) -> (weights (T,k), ids (T,k), aux_loss scalar)."""
+def route(cfg, router_w, tokens, bias=None):
+    """tokens (T, D) -> (weights (T,k), ids (T,k), aux_loss scalar).
+
+    ``topk_method="noaux_tc"`` is DeepSeek-V3's rule (its ``MoEGate``):
+    with scores ``s`` over all experts, the choice scores ``s + bias``;
+    each of ``n_group`` groups scores the sum of its two best choice
+    scores; the ``topk_group`` best groups are kept and the ``top_k``
+    best choice scores among their experts taken.  The weights are
+    ``s`` (not ``s + bias``) at those experts, normalised to sum 1
+    (DeepSeek-V3's ``norm_topk_prob``), times ``routed_scaling_factor``.
+    """
     m = cfg.moe
     logits = jnp.einsum("td,de->te", tokens.astype(jnp.float32),
                         router_w.astype(jnp.float32))
@@ -64,8 +78,24 @@ def route(cfg, router_w, tokens):
         scores = jax.nn.sigmoid(logits)
     else:
         scores = jax.nn.softmax(logits, axis=-1)
-    w, ids = jax.lax.top_k(scores, m.top_k)
-    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    if m.topk_method == "noaux_tc":
+        T = scores.shape[0]
+        choice = scores if bias is None \
+            else scores + bias.astype(jnp.float32)[None]
+        groups = choice.reshape(T, m.n_group, m.n_experts // m.n_group)
+        group_score = jax.lax.top_k(groups, 2)[0].sum(-1)    # (T, n_group)
+        _, keep = jax.lax.top_k(group_score, m.topk_group)
+        kept = jnp.zeros((T, m.n_group), bool).at[
+            jnp.arange(T)[:, None], keep].set(True)
+        kept = jnp.repeat(kept, m.n_experts // m.n_group, axis=1)
+        _, ids = jax.lax.top_k(jnp.where(kept, choice, -jnp.inf), m.top_k)
+        w = jnp.take_along_axis(scores, ids, axis=-1)
+        if m.top_k > 1:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        w = w * m.routed_scaling_factor
+    else:
+        w, ids = jax.lax.top_k(scores, m.top_k)
+        w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
     # Switch-style load-balance aux: E * sum_e f_e * p_e
     probs = scores / jnp.maximum(scores.sum(-1, keepdims=True), 1e-9)
     f = jnp.zeros((m.n_experts,), jnp.float32).at[ids.reshape(-1)].add(1.0)
@@ -109,7 +139,7 @@ def _group_count(E: int, C: int, D: int, budget_bytes: int = 1 << 27) -> int:
     return E // eg
 
 
-def local_moe(cfg, tokens, router_w, e_gate, e_up, e_down):
+def local_moe(cfg, tokens, router_w, e_gate, e_up, e_down, bias=None):
     """Dense-math MoE on local tokens. tokens (T, D) -> (out (T, D), aux).
 
     e_* weights may be sharded on the ffn dim (expert-TP): the result is
@@ -121,7 +151,7 @@ def local_moe(cfg, tokens, router_w, e_gate, e_up, e_down):
     C = capacity(cfg, T)
     act = nn.activation(cfg.act)
 
-    w, ids, aux = route(cfg, router_w, tokens)
+    w, ids, aux = route(cfg, router_w, tokens, bias)
     slot_tok, slot = dispatch_indices(ids, T, m.top_k, E, C)
     slot_w = jnp.zeros((E * C,), tokens.dtype).at[slot].set(
         w.reshape(-1).astype(tokens.dtype), mode="drop")
@@ -159,16 +189,71 @@ def local_moe(cfg, tokens, router_w, e_gate, e_up, e_down):
 
 
 # ---------------------------------------------------------------------------
+# dropless inference over the held experts
+# ---------------------------------------------------------------------------
+def apply_dropless(p, cfg, x):
+    """Inference path: x (..., D) -> (out (..., D), held pairs (...,)).
+
+    The router scores all ``n_experts`` (in float32, as the published
+    gate does); of each token's ``top_k`` (token, expert) pairs those
+    on the experts this device holds
+    (``MoEConfig.held`` from ``first_held``) are sorted by expert and
+    run through one grouped matmul per expert matrix
+    (``jax.lax.ragged_dot``: on the chip a grouped-matmul kernel that
+    visits only the rows of each group), weighted and added back to
+    their tokens.  Nothing is dropped and no capacity is set, so a
+    token's output does not depend on the other tokens of its batch.
+    Pairs on experts held elsewhere are left out: on one device of an
+    expert-parallel deployment this is the device's part of the layer.
+    The second output counts each token's pairs on held experts."""
+    m = cfg.moe
+    lead, D = x.shape[:-1], x.shape[-1]
+    tokens = x.reshape(-1, D)
+    T, k, E_h = tokens.shape[0], m.top_k, m.held
+    with jax.named_scope("router"):
+        w, ids, _ = route(cfg, p["router_w"], tokens, p.get("router_bias"))
+        local = ids - m.first_held
+        mine = (local >= 0) & (local < E_h)
+        expert = jnp.where(mine, local, E_h).reshape(-1)     # (T*k,)
+        # a token has at most min(k, E_h) pairs on held experts, and the
+        # stable sort puts the others last
+        order = jnp.argsort(expert, stable=True)[:T * min(k, E_h)]
+        sizes = jnp.zeros((E_h + 1,), jnp.int32).at[expert].add(1)[:E_h]
+        tok = order // k
+        weight = jnp.where(mine.reshape(-1), w.reshape(-1), 0.0)[order]
+    with jax.named_scope("experts"):
+        xs = tokens[tok]
+        act = nn.activation(cfg.act)
+
+        def grouped(a, wmat):
+            return jax.lax.ragged_dot(a, wmat, sizes,
+                                      preferred_element_type=jnp.float32)
+        up = grouped(xs, p["e_up"])
+        h = act(grouped(xs, p["e_gate"])) * up if "e_gate" in p \
+            else act(up)
+        y = grouped(h.astype(tokens.dtype), p["e_down"]) * weight[:, None]
+        out = jnp.zeros((T, D), jnp.float32).at[tok].add(y)
+    return (out.astype(x.dtype).reshape(x.shape),
+            mine.sum(-1).astype(jnp.int32).reshape(lead))
+
+
+# ---------------------------------------------------------------------------
 # sharded entry point
 # ---------------------------------------------------------------------------
 def apply(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x (B, S, D) -> (out (B, S, D), aux loss scalar)."""
+    """x (B, S, D) -> (out (B, S, D), aux loss scalar): the training
+    path, with capacity routing over all experts."""
+    if cfg.moe.held != cfg.moe.n_experts:
+        raise NotImplementedError(
+            "capacity routing needs every expert held; a device holding "
+            "part of them serves through apply_dropless")
     env = current_env()
     e_gate = p.get("e_gate")
     if env is None:
         B, S, D = x.shape
         out, aux = local_moe(cfg, x.reshape(B * S, D), p["router_w"],
-                             e_gate, p["e_up"], p["e_down"])
+                             e_gate, p["e_up"], p["e_down"],
+                             p.get("router_bias"))
         return out.reshape(B, S, D), aux
 
     mesh = env.mesh

@@ -172,6 +172,12 @@ class PagedEngine:
     requests_recovered = counter_attr()
     tokens_recomputed = counter_attr()  # emitted tokens discarded by resets
     quarantined_served = counter_attr()  # MUST stay 0: stale-read guard hits
+    # routed-expert layers, from the fused decode windows' own counts
+    # (pulled with their tokens): tokens through expert layers, one per
+    # occupied slot, step and layer, and their (token, expert) pairs
+    # that landed on the experts this device holds
+    moe_tokens = counter_attr()
+    moe_pairs_held = counter_attr()
 
     def __init__(self, cfg, params, *, max_batch: int = 4,
                  page_size: int = 16, n_pages: int = 64,
@@ -186,10 +192,11 @@ class PagedEngine:
                  trace_capacity: int = 4096, mesh=None):
         import jax
         import jax.numpy as jnp
-        from repro.models import lm, modules as nn
+        from repro.models import lm
 
         assert lm.paged_decodable(cfg), \
-            f"{cfg.name} is not paged-decodable (attention-only, causal)"
+            f"{cfg.name} is not paged-decodable (global or latent " \
+            "attention layers only, causal)"
         assert spec_proposer in ("device", "host")
         # mesh: a jax Mesh whose "model" axis stripes the page pools
         # (page p lives on node p % M via core/memory_server
@@ -208,6 +215,11 @@ class PagedEngine:
                     f"n_nodes={n_nodes} disagrees with the mesh's model "
                     f"degree {self._stripe}: the host allocator's stripe "
                     "and the device stripe must be the same partition")
+            if "mla" in cfg.layer_kinds:
+                raise NotImplementedError(
+                    "latent (mla) pools are not striped over a mesh: "
+                    "the striped decode merges GQA partials only; serve "
+                    f"{cfg.name} on one device")
             if n_pages % self._stripe:
                 raise ValueError(
                     f"n_pages={n_pages} not divisible by the stripe "
@@ -290,12 +302,10 @@ class PagedEngine:
         self._verify = steps["verify"]
         self._spec_step = steps["spec"]
         self._copy_page = steps["copy_page"]
-        # KV bytes one token occupies across the whole stack (k + v, every
-        # layer) — the unit behind the bytes_deduped gauge
-        self.kv_bytes_per_token = (cfg.n_layers * 2 * cfg.n_kv_heads
-                                   * cfg.head_dim
-                                   * np.dtype(nn.dt(cfg.activation_dtype))
-                                   .itemsize)
+        # pool bytes one token occupies across the whole stack (k + v of
+        # a global-attention layer, a latent row of a latent one) — the
+        # unit behind the bytes_deduped gauge
+        self.kv_bytes_per_token = lm.paged_bytes_per_token(cfg)
         # host MIRROR of slot state; the device copies are authoritative
         # between window boundaries
         self.block_tables = np.full((max_batch, self.nmax), NULL_PAGE,
@@ -346,6 +356,8 @@ class PagedEngine:
         self.requests_recovered = 0
         self.tokens_recomputed = 0
         self.quarantined_served = 0
+        self.moe_tokens = 0
+        self.moe_pairs_held = 0
         # dispatch-span attribution: (predicted seconds, predicted §VI
         # joules across the fleet) per prefill-shaped width, memoized —
         # the cost engine prices each width once
@@ -835,7 +847,7 @@ class PagedEngine:
                                  jnp.int32)
             inactive = jnp.zeros((self.max_batch,), jnp.int32)
             for k in self.window_sizes():
-                toks, _, _, self.pools = self._scan(
+                toks, _, _, self.pools, *_ = self._scan(
                     self.params, zeros_tok, self.pools, null_rows,
                     zeros_pos, inactive, k=k)
                 np.asarray(toks)
@@ -1167,13 +1179,13 @@ class PagedEngine:
         if scan_slots:
             t_scan = time.perf_counter()
             with self._span("dispatch"):
-                toks, d_tok, d_pos, self.pools = self._scan(
+                toks, d_tok, d_pos, self.pools, *counts = self._scan(
                     self.params, self.d_tokens, self.pools, d_bt,
                     self.d_pos, d_act, k=kk)
             with self._span("pull", lambda: self._predict_scan(kk),
                             program="scan", since=t_scan, k=kk,
                             slots=len(scan_slots)):
-                tok_np = np.asarray(toks).reshape(self.max_batch, kk)
+                tok_np = self._pull_window(toks, counts, kk)
             with self._span("complete"):
                 self.d2h_syncs += 1
                 self.decode_steps += kk
@@ -1299,11 +1311,12 @@ class PagedEngine:
             active = dict(self.sched.running)
             t_dec = time.perf_counter()
             self._push(force=not self.fused)
+        counts = ()
         with self._span("dispatch"):
             if self.fused:
-                toks, self.d_tokens, self.d_pos, self.pools = self._scan(
-                    self.params, self.d_tokens, self.pools, self.d_block,
-                    self.d_pos, self.d_active, k=k)
+                toks, self.d_tokens, self.d_pos, self.pools, *counts = \
+                    self._scan(self.params, self.d_tokens, self.pools,
+                               self.d_block, self.d_pos, self.d_active, k=k)
             else:
                 # legacy per-step path: push the whole bundle and pull one
                 # token per scheduler step — O(1 syncs per token)
@@ -1312,7 +1325,7 @@ class PagedEngine:
                     self.d_pos)
         with self._span("pull", lambda: self._predict_scan(k),
                         program="scan", since=t_dec, k=k, slots=len(active)):
-            tok_np = np.asarray(toks).reshape(self.max_batch, k)
+            tok_np = self._pull_window(toks, counts, k)
         self.d2h_syncs += 1
         self.decode_time_s += time.perf_counter() - t_dec
         finished: List[Request] = []
@@ -1334,6 +1347,17 @@ class PagedEngine:
                 self.pos[slot] = req.pos
             self.steps_run += k
         return finished
+
+    def _pull_window(self, toks, counts, k: int) -> np.ndarray:
+        """The window's tokens (max_batch, k) and, for a model with
+        routed experts, its ``[moe_tokens, moe_pairs_held]`` counts
+        (added to the counters), in one device-to-host transfer."""
+        import jax
+        tok_np, counts = jax.device_get((toks, tuple(counts)))
+        for n_tok, n_held in counts:
+            self.moe_tokens += int(n_tok)
+            self.moe_pairs_held += int(n_held)
+        return np.asarray(tok_np).reshape(self.max_batch, k)
 
     def step(self, max_window: Optional[int] = None) -> List[Request]:
         """Plan, prefill admissions, decode one fused window (or one

@@ -129,8 +129,8 @@ def make_paged_serve_step(cfg: ModelConfig):
     """
     @_scoped
     def serve_paged(params, tokens, pools, block_tables, pos):
-        logits, pools = lm.decode_step_paged(params, cfg, tokens, pools,
-                                             block_tables, pos)
+        logits, pools, _ = lm.decode_step_paged(params, cfg, tokens, pools,
+                                                block_tables, pos)
         with jax.named_scope("head"):
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_tok, logits, constrain_paged_pools(pools)
@@ -296,7 +296,10 @@ def make_paged_serve_scan(cfg: ModelConfig):
 
     (params, tokens (B,1), pools, block_tables (B,nmax), pos (B,),
      active (B,), k) -> (emitted (B,K), last tokens (B,1), pos (B,),
-    updated pools).  ``k`` is the scan length — jit with
+    updated pools), and for a model with routed experts also the
+    window's counters ``[moe_tokens, moe_pairs_held]`` (int32 (2,),
+    ``lm.decode_window_paged``), pulled with the emitted tokens in the
+    window's one sync.  ``k`` is the scan length — jit with
     ``static_argnames=("k",)`` and the pools donated; one dispatch and
     one host sync then cover K decode steps instead of one.  The pools
     stay in the donated buffer for the whole window: the window scan
@@ -307,9 +310,10 @@ def make_paged_serve_scan(cfg: ModelConfig):
     @_scoped
     def serve_scan(params, tokens, pools, block_tables, pos, active, *,
                    k: int):
-        emitted, last, pos, pools = lm.decode_window_paged(
+        emitted, last, pos, pools, counts = lm.decode_window_paged(
             params, cfg, tokens, pools, block_tables, pos, active, k)
-        return emitted, last, pos, constrain_paged_pools(pools)
+        out = (emitted, last, pos, constrain_paged_pools(pools))
+        return out if counts is None else out + (counts,)
     return serve_scan
 
 

@@ -60,8 +60,15 @@ def test_rwkv_chunked_matches_ref():
                                   "recurrentgemma-2b", "rwkv6-1.6b",
                                   "deepseek-v3-671b"])
 def test_decode_matches_forward(arch):
-    """Prefill(S) + decode(token S) logits == forward(S+1) last logits."""
+    """Prefill(S) + decode(token S) logits == forward(S+1) last logits.
+    The training forward routes experts into a fixed capacity and drops
+    the overflow, the inference path drops nothing: the capacity here
+    holds every token, so the two compute the same function."""
     cfg = get_tiny_config(arch).replace(impl="ref")
+    if cfg.moe is not None:
+        import dataclasses
+        cfg = cfg.replace(moe=dataclasses.replace(
+            cfg.moe, capacity_factor=cfg.moe.n_experts / cfg.moe.top_k))
     params = lm.init_params(jax.random.PRNGKey(0), cfg)
     B, S = 2, 16
     toks = jax.random.randint(jax.random.PRNGKey(3), (B, S + 1), 0,

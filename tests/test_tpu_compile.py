@@ -209,3 +209,53 @@ def test_paged_programs_update_pools_in_place(one_chip, program):
     assert len(scatters) >= 2, scatters           # k and v, in place
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < stack.size * stack.dtype.itemsize, temp
+
+
+# DeepSeek-V3 as the deepseek-v3.docqa cell serves it: one chip's share
+# (1 dense + 4 expert layers, 8 of 256 experts, 16,160 vocabulary rows)
+# at published widths, bf16, batch 16, page 16, requests up to 32,272
+# tokens, the pool the exact worst case (32,273 latent pages)
+DS_NMAX = 32272 // 16
+
+
+@pytest.mark.parametrize("program", ["serve_scan", "chunk_prefill"])
+def test_latent_programs_fit_a_v5e_at_the_cells_size(one_chip, program):
+    """The decode window (k=8, absorbed latent decode over the whole
+    block row) and the 512-token chunk (the up-projected latent path,
+    key block by key block) compile for one v5e with their arguments
+    (weights and the 2.98 GB latent pool, donated) and temporaries under
+    its 15.75 GiB, the dropless expert layer as a grouped-matmul
+    kernel."""
+    import dataclasses
+    from repro import steps
+    from repro.configs import get_config
+    from repro.models import lm, mla
+    full = get_config("deepseek-v3-671b")
+    cfg = full.replace(n_layers=5, first_k_dense=1, vocab_size=16160,
+                       mtp_depth=0, activation_dtype="bfloat16",
+                       moe=dataclasses.replace(full.moe, n_held=8))
+    assert not mla.absorbed_cheaper(cfg, 512) and mla.absorbed_cheaper(cfg,
+                                                                       128)
+    n_pages = SB * DS_NMAX + 1
+    to = lambda t: jax.tree.map(                          # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = to(jax.eval_shape(lambda k: lm.init_params(k, cfg),
+                               jax.random.PRNGKey(0)))
+    pools = to(jax.eval_shape(lambda: lm.init_paged_caches(cfg, n_pages, PS)))
+    assert jax.tree.leaves(pools)[1].shape == (4, n_pages, PS, 576)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,   # noqa: E731
+                                          sharding=one_chip)
+    if program == "serve_scan":
+        compiled = jax.jit(steps.make_paged_serve_scan(cfg),
+                           static_argnames=("k",), donate_argnums=(2,)).lower(
+            params, i32(SB, 1), pools, i32(SB, DS_NMAX), i32(SB), i32(SB),
+            k=8).compile()
+    else:
+        compiled = jax.jit(steps.make_chunk_prefill(cfg),
+                           donate_argnums=(2,)).lower(
+            params, i32(1, 512), pools, i32(DS_NMAX), i32(), i32()).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < 15.75 * 2 ** 30, used
+    assert "ragged-dot" in compiled.as_text()
